@@ -21,9 +21,11 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
+import runpy
 import subprocess
 import sys
 import time
+import traceback
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -98,17 +100,15 @@ def run_trajectory(out_dir: pathlib.Path, rows, out,
         out(f"[serve benchmarks {time.time()-t0:.1f}s]")
 
         # the shared-prefix example doubles as an end-to-end smoke: it
-        # asserts oracle identity + the capacity win on its own workload
+        # asserts oracle identity + the capacity win on its own workload.
+        # It runs in this process, which already holds the device.
         t0 = time.time()
-        env = host_device_env(1)
-        env.setdefault("PYTHONPATH", str(REPO_ROOT / "src"))
-        r = subprocess.run(
-            [sys.executable, str(REPO_ROOT / "examples"
-                                 / "prefix_sharing.py")],
-            env=env, cwd=str(REPO_ROOT), capture_output=True, text=True)
-        if r.returncode != 0:
+        try:
+            runpy.run_path(str(REPO_ROOT / "examples" / "prefix_sharing.py"),
+                           run_name="prefix_sharing")["main"]([])
+        except AssertionError:
             ok = False
-            out(f"[prefix-sharing example FAILED]\n{r.stdout}\n{r.stderr}")
+            out(f"[prefix-sharing example FAILED]\n{traceback.format_exc()}")
         out(f"[prefix-sharing example {time.time()-t0:.1f}s]")
     return ok
 

@@ -6,17 +6,16 @@ modes (layers / allreduce / scatter) are compared for collective bytes on
 the compiled HLO.
 
     PYTHONPATH=src python examples/heterogeneous_matmul.py
-(re-executes itself with 8 host devices)
+(runs on 8 virtual CPU devices)
 """
 
 import os
-import subprocess
-import sys
 
-if os.environ.get("XLA_FLAGS", "").find("device_count") < 0:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    raise SystemExit(subprocess.run([sys.executable] + sys.argv, env=env).returncode)
+# eight virtual CPU devices, asked for before jax is imported;
+# JAX_PLATFORMS=cpu keeps the script off a TPU the host may have
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=8").strip()
 
 import jax
 import jax.numpy as jnp

@@ -13,19 +13,16 @@ both execution planes on an 8-device mesh:
              collectives in the lowered HLO.
 
     PYTHONPATH=src python examples/overlap_streaming.py
-(re-executes itself with 8 host devices)
+(runs on 8 virtual CPU devices)
 """
 
 import os
-import subprocess
-import sys
 
-if os.environ.get("XLA_FLAGS", "").find("device_count") < 0:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    raise SystemExit(subprocess.run([sys.executable] + sys.argv,
-                                    env=env).returncode)
+# eight virtual CPU devices, asked for before jax is imported;
+# JAX_PLATFORMS=cpu keeps the script off a TPU the host may have
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=8").strip()
 
 import jax
 import numpy as np

@@ -39,10 +39,10 @@ def run(params, cfg, rules, workload, *, sharing):
     return eng, eng.run()
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3_2_3b")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_reduced(args.arch)
     rules = Rules.null()

@@ -1,124 +1,24 @@
-"""Version-adaptive JAX compatibility layer — the single import point.
+"""The single import point for JAX's distributed API.
 
-The distributed API surface this repo depends on has drifted across the
-jax versions it must run on:
-
-  * ``shard_map`` moved: ``jax.experimental.shard_map.shard_map``
-    (jax <= 0.5.x) -> top-level ``jax.shard_map`` (newer), and its
-    replication-check kwarg was renamed ``check_rep`` -> ``check_vma``.
-  * ``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=)``
-    (explicit-sharding API) do not exist on jax 0.4.x at all.
-
-Every module in the repo resolves these names HERE; nothing else may
-version-sniff jax (enforced by the tier-1 grep check).  Feature flags let
-callers branch on capability instead of version string:
-
-  JAX_VERSION              (major, minor, patch) ints parsed from jax.__version__
-  HAS_AXIS_TYPE            jax.sharding.AxisType exists
-  HAS_TOPLEVEL_SHARD_MAP   jax.shard_map exists
-  SHARD_MAP_CHECK_KWARG    "check_vma" | "check_rep" | None (name accepted by
-                           the resolved shard_map implementation)
-
-The ``_resolve_*``/``_build_*`` helpers take the (possibly fake) jax
-module as an argument so tests can exercise both old- and new-API shapes
-without installing a second jax.
+The repo supports the installed jax (0.9.0) only.  Every module takes
+``shard_map``, ``make_mesh`` and ``cost_analysis`` from here rather than
+from jax, so when jax moves or renames one of them the fix lands in this
+file alone (CI greps ``src/`` for direct uses outside it).
 """
 
 from __future__ import annotations
 
-import inspect
-import math
-import re
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
-import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported)
 
+AxisType = jax.sharding.AxisType
 
-def _version_tuple(version: str) -> Tuple[int, int, int]:
-    parts = []
-    for piece in version.split(".")[:3]:
-        # leading digits only: "37rc1" is 37, a pure suffix like "dev123"
-        # contributes nothing (concatenating all digits would turn an rc
-        # into a huge patch number)
-        m = re.match(r"\d+", piece)
-        parts.append(int(m.group()) if m else 0)
-    while len(parts) < 3:
-        parts.append(0)
-    return tuple(parts)  # type: ignore[return-value]
-
-
-JAX_VERSION: Tuple[int, int, int] = _version_tuple(jax.__version__)
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-HAS_AXIS_TYPE: bool = AxisType is not None
-HAS_TOPLEVEL_SHARD_MAP: bool = callable(getattr(jax, "shard_map", None))
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-def _check_kwarg_name(fn) -> Optional[str]:
-    """Which replication-check kwarg does this shard_map accept?"""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return None
-    if "check_vma" in params:
-        return "check_vma"
-    if "check_rep" in params:
-        return "check_rep"
-    return None
-
-
-def _resolve_shard_map(jax_module):
-    """Return (implementation, check_kwarg_name) for this jax module."""
-    impl = getattr(jax_module, "shard_map", None)
-    if not callable(impl):
-        exp = getattr(jax_module, "experimental", None)
-        sub = getattr(exp, "shard_map", None) if exp is not None else None
-        if sub is None and jax_module is jax:
-            from jax.experimental import shard_map as sub  # noqa: PLC0415
-        impl = getattr(sub, "shard_map", None) if sub is not None else None
-    if impl is None:
-        raise ImportError(
-            "could not resolve shard_map: neither jax.shard_map nor "
-            "jax.experimental.shard_map.shard_map exists")
-    return impl, _check_kwarg_name(impl)
-
-
-def _build_shard_map(impl, check_kwarg: Optional[str]):
-    """Wrap a resolved implementation behind the new-style signature.
-
-    The wrapper always accepts ``check_vma`` (the newest name) and
-    translates it to whatever the implementation understands, dropping it
-    when the implementation predates both spellings.
-    """
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        kw = dict(kwargs, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if check_vma is not None and check_kwarg is not None:
-            kw[check_kwarg] = check_vma
-        return impl(f, **kw)
-
-    return shard_map
-
-
-_SHARD_MAP_IMPL, SHARD_MAP_CHECK_KWARG = _resolve_shard_map(jax)
-shard_map = _build_shard_map(_SHARD_MAP_IMPL, SHARD_MAP_CHECK_KWARG)
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
 
 def _resolve_axis_types(axis_types, n_axes: int):
-    """Normalize user axis_types ("auto" | AxisType | sequence) to a tuple
-    of AxisType, or None when this jax has no AxisType (degrade: the
-    pre-explicit-sharding default behaves like Auto everywhere)."""
-    if not HAS_AXIS_TYPE:
-        return None
+    """Normalize "auto" | "explicit" | "manual" | AxisType, or a per-axis
+    sequence of them, to a tuple of AxisType."""
     if axis_types is None:
         axis_types = "auto"
     if isinstance(axis_types, str) or not isinstance(axis_types, (tuple, list)):
@@ -137,67 +37,24 @@ def _resolve_axis_types(axis_types, n_axes: int):
     return tuple(one(t) for t in axis_types)
 
 
-def _mesh_from_devices(axis_shapes, axis_names, devices):
-    """Oldest-API fallback: build a Mesh by hand from a device list."""
-    n = math.prod(axis_shapes)
-    if len(devices) < n:
-        raise ValueError(f"need {n} devices for mesh {axis_shapes}, "
-                         f"have {len(devices)}")
-    grid = np.asarray(devices[:n], dtype=object).reshape(axis_shapes)
-    return jax.sharding.Mesh(grid, axis_names)
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, axis_types=None):
-    """Version-portable ``jax.make_mesh``.
-
-    ``axis_types`` accepts the new-API values ("auto" / "explicit" /
-    "manual", an AxisType, or a per-axis sequence) and is silently dropped
-    on jax builds without ``jax.sharding.AxisType`` — those versions have
-    exactly one (auto) behavior, so dropping loses nothing.
-    """
+    """``jax.make_mesh`` with ``axis_types`` also accepted as strings
+    ("auto" / "explicit" / "manual"); auto by default."""
     axis_shapes = tuple(int(s) for s in axis_shapes)
     axis_names = tuple(axis_names)
-    resolved = _resolve_axis_types(axis_types, len(axis_names))
     kwargs = {} if devices is None else {"devices": devices}
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        # decide by signature, not by catching TypeError: a swallowed
-        # TypeError from inside make_mesh would silently downgrade a
-        # requested explicit/manual mesh to the auto default
-        if resolved is not None:
-            try:
-                accepts = "axis_types" in inspect.signature(mk).parameters
-            except (TypeError, ValueError):
-                accepts = True
-            if accepts:
-                kwargs["axis_types"] = resolved
-        return mk(axis_shapes, axis_names, **kwargs)
-    return _mesh_from_devices(axis_shapes, axis_names,
-                              devices if devices is not None else jax.devices())
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=_resolve_axis_types(axis_types,
+                                                        len(axis_names)),
+                         **kwargs)
 
-
-# ---------------------------------------------------------------------------
-# compiled-artifact introspection
-# ---------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to one flat dict.
-
-    jax <= 0.4.x returns a one-element LIST of per-program dicts; newer
-    jax returns the dict directly.  Returns {} when the backend offers no
-    cost model at all.
-    """
+    """``compiled.cost_analysis()`` as a dict; {} when the backend offers
+    no cost model."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for entry in cost:
-            if isinstance(entry, dict):
-                merged.update(entry)
-        return merged
-    return dict(cost)
+    return dict(cost) if cost else {}

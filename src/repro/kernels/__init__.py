@@ -8,8 +8,9 @@
                        resident across the time loop — kills the per-step
                        HBM weight re-reads that dominate xlstm's roofline)
 
-ops.py holds the jit'd padded wrappers (interpret=True off-TPU); ref.py the
-oracles; tests/test_kernels.py the shape/dtype sweeps.
+ops.py holds the jit'd padded wrappers (off the TPU, ask for
+interpret=True); ref.py the oracles; tests/test_kernels.py the
+shape/dtype sweeps.
 """
 
 from . import ops, ref  # noqa: F401

@@ -4,8 +4,10 @@ Every wrapper:
   * pads inputs to block multiples (zero padding is exact for all three
     kernels: matmul layers, attention KV with -inf masking via extra keys
     being zero... see notes), slices the result back;
-  * runs the kernel in ``interpret=True`` when not on a TPU backend (this
-    container is CPU-only; TPU is the deployment target);
+  * compiles the kernel for the TPU unless the caller passes
+    ``interpret=True`` (the CPU tests do); the mode is never inferred
+    from the backend, so a missing chip fails instead of silently
+    interpreting;
   * has a pure-jnp oracle in ref.py used by the test sweeps.
 """
 
@@ -19,10 +21,6 @@ import jax.numpy as jnp
 from .flash_attention_kernel import flash_attention_pallas
 from .lbp_matmul_kernel import lbp_matmul_pallas
 from .rglru_kernel import rglru_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -39,13 +37,11 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
                                              "out_dtype", "interpret"))
 def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 512,
            block_n: int = 512, block_k: int = 512, out_dtype=None,
-           interpret: bool | None = None) -> jax.Array:
+           interpret: bool = False) -> jax.Array:
     """Layer-accumulating blocked matmul; pads (M, K, F) to block multiples.
 
     Zero-padding K adds all-zero layers — exact by Theorem-1 linearity.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     m, k = x.shape
     f = w.shape[1]
     xp = _pad_to(_pad_to(x, 0, block_m), 1, block_k)
@@ -58,7 +54,7 @@ def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 512,
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
 def rglru(a: jax.Array, b: jax.Array, h0: jax.Array, *, block_d: int = 512,
-          chunk: int = 256, interpret: bool | None = None):
+          chunk: int = 256, interpret: bool = False):
     """Gated linear recurrence h_t = a_t h_{t-1} + b_t over long sequences.
 
     Chunks the sequence (kernel holds one chunk in VMEM) and carries h
@@ -66,8 +62,6 @@ def rglru(a: jax.Array, b: jax.Array, h0: jax.Array, *, block_d: int = 512,
     channels recur on zeros — exact).
     Returns (h: (B,S,D), h_end: (B,D)).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     B, S, D = a.shape
     ap = _pad_to(a, 2, block_d)
     bp = _pad_to(b, 2, block_d)
@@ -96,7 +90,7 @@ def rglru(a: jax.Array, b: jax.Array, h0: jax.Array, *, block_d: int = 512,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def slstm(pre, R, state, *, chunk: int = 256, interpret: bool | None = None):
+def slstm(pre, R, state, *, chunk: int = 256, interpret: bool = False):
     """Weight-stationary sLSTM over long sequences (chunked, carried state).
 
     pre: dict z/i/f/o -> (B,S,H,hd); R: dict -> (H,hd,hd);
@@ -104,8 +98,6 @@ def slstm(pre, R, state, *, chunk: int = 256, interpret: bool | None = None):
     """
     from .slstm_kernel import slstm_pallas
 
-    if interpret is None:
-        interpret = not _on_tpu()
     B, S, H, hd = pre["z"].shape
     c = min(chunk, S)
     while S % c:
@@ -129,7 +121,7 @@ def slstm(pre, R, state, *, chunk: int = 256, interpret: bool | None = None):
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool | None = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Blocked online-softmax attention, (B, H, S, D) layout.
 
     Query padding rows attend causally to real keys (sliced away); key/value
@@ -138,8 +130,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal mask of every real query row excludes.  For non-causal use, T
     must already be a block multiple (asserted).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     B, H, S, D = q.shape
     T = k.shape[2]
     qf = q.reshape(B * H, S, D)

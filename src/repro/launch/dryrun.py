@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective artifacts.
 
@@ -15,7 +11,16 @@ Artifacts (bytes/device, HLO FLOPs, collective bytes) land in
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3_2_3b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--profile train_sp]
+
+Runs on 512 virtual CPU devices, which it asks for itself before jax is
+imported: ``JAX_PLATFORMS=cpu`` keeps it off a TPU the host may have.
 """
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512").strip()
 
 import argparse
 import json
@@ -29,6 +34,7 @@ from ..obs import clock as obs_clock
 from ..compat import cost_analysis as compat_cost_analysis
 from ..configs import ARCH_IDS
 from ..configs.shapes import cells_for
+from .compile_cache import use_compile_cache
 from .input_specs import make_plan
 from .mesh import make_production_mesh
 
@@ -109,6 +115,7 @@ def main():
     ap.add_argument("--tuning", default="",
                     help="comma list k=true/false for models.tuning flags")
     args = ap.parse_args()
+    use_compile_cache()
 
     tuning = {}
     for kv in filter(None, args.tuning.split(",")):

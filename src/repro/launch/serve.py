@@ -1,10 +1,18 @@
-"""Serving launcher: continuous-batching engine over the reduced configs.
+"""Serving launcher: continuous-batching engine at published widths.
 
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_3b --paged
   PYTHONPATH=src python -m repro.launch.serve --arch llama3_2_3b --demo
 
-``--demo`` serves a batch of synthetic staggered-arrival prompts through
-``serve.engine.ServingEngine`` on local devices and reports prefill
+Serves a batch of synthetic staggered-arrival prompts through
+``serve.engine.ServingEngine`` on the local device and reports prefill
 latency (time-to-first-token) separately from decode throughput.
+Without ``--demo`` the model is the architecture's published config,
+with q heads padded only to the model-axis size (1 on one chip: no
+padding); ``--demo`` serves the reduced same-family config instead.
+Weights are random, drawn from ``--seed`` by one jitted init that emits
+the config's dtype (bf16), so no float32 copy of the weights is ever
+held on the device.
+
 ``--paged`` switches the KV cache to the paged plane (fixed-size token
 pages + per-request page tables; admission gated on free pages) —
 outputs are token-identical to the slot plane by construction.
@@ -29,16 +37,21 @@ bounds the drain in ticks so a wedged schedule fails loud, never hangs.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ..configs import ARCH_IDS, get_reduced
+from ..configs import ARCH_IDS, get_config, get_reduced
 from ..models import transformer as T
+from ..models.config import ModelConfig
 from ..obs import MetricsRegistry, Tracer, write_chrome_trace
-from ..serve import (EngineConfig, PagedTransformerModel, ServingEngine,
-                     TransformerModel, greedy_generate)
+from ..serve import (EngineConfig, EngineReport, PagedTransformerModel,
+                     ServingEngine, TransformerModel, greedy_generate)
 from ..sharding.rules import Rules
+from .compile_cache import use_compile_cache
 
 
 def _positive_int(flag: str):
@@ -78,24 +91,54 @@ def build_workload(args, vocab_size: int):
             n_templates=max(1, min(4, args.batch // 3)),
             template_len=template_len,
             suffix_lens=tuple(sorted({max(2, suffix_max // 2), suffix_max})),
-            news=news, stagger=1.0 / max(1, args.slots))
+            news=news, stagger=1.0 / max(1, args.slots), seed=args.seed)
     lens = sorted({max(2, args.prompt_len // 4), max(2, args.prompt_len // 2),
                    max(2, (3 * args.prompt_len) // 4), args.prompt_len})
     return synthetic_workload(args.batch, vocab_size, lens=lens,
                               news=(args.max_new,),
-                              stagger=1.0 / max(1, args.slots))
+                              stagger=1.0 / max(1, args.slots),
+                              seed=args.seed)
 
 
-def main(argv=None):
+def serving_config(arch: str, demo: bool, rules: Rules) -> ModelConfig:
+    """The config the launcher serves: the reduced variant under --demo,
+    else the published widths with the head-padding quantum set to the
+    model-axis size — 1 for single-device rules, so no q head is padded."""
+    if demo:
+        return get_reduced(arch)
+    tp = 1 if rules.mesh is None else int(rules.mesh.shape["model"])
+    return dataclasses.replace(get_config(arch), tp=tp)
+
+
+def param_init(cfg: ModelConfig):
+    """Jitted parameter init whose outputs are ``cfg.dtype``.  The float32
+    draw of ``T.init_params`` exists only inside the one program, so the
+    device never holds a float32 weight tree (6 GiB of bf16 weights for
+    llama3_2_3b would be 12 GiB in float32)."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def init(key):
+        return jax.tree_util.tree_map(lambda a: a.astype(dtype),
+                                      T.init_params(cfg, key))
+    return jax.jit(init)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3_2_3b")
     ap.add_argument("--demo", action="store_true",
-                    help="serve the synthetic staggered workload (also the "
-                         "default behaviour; kept for script compatibility)")
+                    help="serve the reduced same-family config "
+                         "(CPU-runnable) instead of the published widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and the workload")
     ap.add_argument("--batch", type=_positive_int("--batch"), default=4)
     ap.add_argument("--prompt-len", type=_positive_int("--prompt-len"),
                     default=32)
     ap.add_argument("--max-new", type=_positive_int("--max-new"), default=16)
+    ap.add_argument("--cache-len", type=_positive_int("--cache-len"),
+                    default=None,
+                    help="KV positions per slot (default: --prompt-len "
+                         "+ --max-new)")
     ap.add_argument("--slots", type=_positive_int("--slots"), default=4,
                     help="continuous-batching cache slots")
     ap.add_argument("--paged", action="store_true",
@@ -168,32 +211,103 @@ def main(argv=None):
     if args.prefix_sharing and not args.paged:
         ap.error("--prefix-sharing needs --paged (slot rows have no page "
                  "granularity to share)")
+    if args.cache_len is not None and (args.cache_len
+                                       < args.prompt_len + args.max_new):
+        ap.error("--cache-len must hold --prompt-len + --max-new tokens")
+    return args
 
-    cfg = get_reduced(args.arch)
-    rules = Rules.null()
-    key = jax.random.PRNGKey(0)
-    params = T.init_params(cfg, key)
-    workload = build_workload(args, cfg.vocab_size)
 
-    if args.fleet:
-        return _serve_fleet(args, params, cfg, rules, workload)
-
-    model_cls = PagedTransformerModel if args.paged else TransformerModel
-    model = model_cls(params, cfg, rules)
-    tracer = Tracer() if args.trace_out else None
-    metrics = MetricsRegistry() if args.metrics_out else None
-    engine = ServingEngine(model, EngineConfig(
+def engine_config(args) -> EngineConfig:
+    return EngineConfig(
         n_slots=args.slots, max_prompt_len=args.prompt_len,
         max_new_cap=args.max_new,
-        cache_len=args.prompt_len + args.max_new,
+        cache_len=args.cache_len or args.prompt_len + args.max_new,
         page_size=args.page_size if args.paged else None,
         n_pages=args.pages if args.paged else None,
-        prefix_sharing=args.prefix_sharing),
-        tracer=tracer, metrics=metrics)
+        prefix_sharing=args.prefix_sharing)
+
+
+def load_model(args) -> Tuple[ModelConfig, Rules, Any]:
+    """(config, rules, params) for ``args``: weights drawn from --seed."""
+    rules = Rules.null()
+    cfg = serving_config(args.arch, args.demo, rules)
+    params = param_init(cfg)(jax.random.PRNGKey(args.seed))
+    return cfg, rules, params
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """Everything one single-engine serving run produced."""
+
+    cfg: ModelConfig
+    rules: Rules
+    params: Any
+    workload: List[Tuple[np.ndarray, int, float]]
+    engine: ServingEngine
+    report: EngineReport
+    tracer: Optional[Tracer] = None
+    metrics: Optional[MetricsRegistry] = None
+
+
+def serve(args) -> ServeRun:
+    """The launcher's single-engine path: build the model and the
+    workload, serve every request, return what came out."""
+    cfg, rules, params = load_model(args)
+    workload = build_workload(args, cfg.vocab_size)
+    model_cls = PagedTransformerModel if args.paged else TransformerModel
+    tracer = Tracer() if args.trace_out else None
+    metrics = MetricsRegistry() if args.metrics_out else None
+    engine = ServingEngine(model_cls(params, cfg, rules), engine_config(args),
+                           tracer=tracer, metrics=metrics)
     for prompt, max_new, arrival in workload:
         engine.submit(prompt, max_new, arrival=arrival)
     report = engine.run()
-    _write_obs(args, tracer, metrics)
+    return ServeRun(cfg, rules, params, workload, engine, report,
+                    tracer, metrics)
+
+
+@dataclasses.dataclass(frozen=True)
+class OracleResult:
+    """One request against ``greedy_generate``.  ``diverge_at`` is None
+    when the tokens are identical; otherwise it is the first differing
+    position and ``margin`` the oracle's logit of its own token minus its
+    logit of the engine's token there (small = a near-tie)."""
+
+    rid: int
+    diverge_at: Optional[int] = None
+    margin: Optional[float] = None
+
+
+def compare_to_oracle(params, cfg: ModelConfig, rules: Rules, workload,
+                      completed: Dict[int, np.ndarray]) -> List[OracleResult]:
+    out = []
+    for rid, (prompt, max_new, _) in enumerate(workload):
+        ref, logits = greedy_generate(params, cfg, rules,
+                                      np.asarray(prompt)[None],
+                                      max_new=max_new, return_logits=True)
+        ref = np.asarray(ref)[0]
+        got = np.asarray(completed[rid])
+        diff = np.flatnonzero(ref != got)
+        if diff.size == 0:
+            out.append(OracleResult(rid))
+            continue
+        j = int(diff[0])
+        row = np.asarray(logits[0, j], np.float32)
+        out.append(OracleResult(rid, j, float(row[ref[j]] - row[got[j]])))
+    return out
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
+    if args.fleet:
+        cfg, rules, params = load_model(args)
+        workload = build_workload(args, cfg.vocab_size)
+        return _serve_fleet(args, params, cfg, rules, workload)
+
+    run = serve(args)
+    engine, report, cfg = run.engine, run.report, run.cfg
+    _write_obs(args, run.tracer, run.metrics)
 
     plane = (f"paged(page_size={args.page_size}, "
              f"pages={engine.pool.n_pages})" if args.paged else "slots")
@@ -221,14 +335,11 @@ def main(argv=None):
           list(map(int, first[:16])))
 
     if args.oracle:
-        for rid, (prompt, max_new, _) in enumerate(workload):
-            ref = np.asarray(greedy_generate(
-                params, cfg, rules, np.asarray(prompt)[None],
-                max_new=max_new))[0]
-            got = report.completed[rid]
-            assert np.array_equal(ref, got), (
-                f"request {rid}: engine {got} != oracle {ref}")
-        print(f"oracle check: {len(workload)} requests token-identical")
+        results = compare_to_oracle(run.params, cfg, run.rules, run.workload,
+                                    report.completed)
+        bad = [r for r in results if r.diverge_at is not None]
+        assert not bad, f"engine != oracle: {bad}"
+        print(f"oracle check: {len(results)} requests token-identical")
 
 
 def _write_obs(args, tracer, metrics):
@@ -252,13 +363,7 @@ def _serve_fleet(args, params, cfg, rules, workload):
 
     tracer = Tracer() if args.trace_out else None
     metrics = MetricsRegistry() if args.metrics_out else None
-    ec = EngineConfig(
-        n_slots=args.slots, max_prompt_len=args.prompt_len,
-        max_new_cap=args.max_new,
-        cache_len=args.prompt_len + args.max_new,
-        page_size=args.page_size if args.paged else None,
-        n_pages=args.pages if args.paged else None,
-        prefix_sharing=args.prefix_sharing)
+    ec = engine_config(args)
 
     def make_model():
         cls = PagedTransformerModel if args.paged else TransformerModel
@@ -336,13 +441,10 @@ def _serve_fleet(args, params, cfg, rules, workload):
         print(f"  {name}: occupancy {report.occupancy[name]:.2f}  "
               f"decode_tokens {report.decode_tokens[name]}")
     if args.oracle:
-        for rid, (prompt, max_new, _) in enumerate(workload):
-            ref = np.asarray(greedy_generate(
-                params, cfg, rules, np.asarray(prompt)[None],
-                max_new=max_new))[0]
-            got = report.completed[rid]
-            assert np.array_equal(ref, got), (
-                f"request {rid}: fleet {got} != oracle {ref}")
+        bad = [r for r in compare_to_oracle(params, cfg, rules, workload,
+                                            report.completed)
+               if r.diverge_at is not None]
+        assert not bad, f"fleet != oracle: {bad}"
         print(f"oracle check: {len(workload)} requests token-identical "
               f"under the fault schedule")
 

@@ -17,6 +17,7 @@ import jax
 from ..configs import ARCH_IDS, get_config, get_reduced
 from ..runtime.trainer import Trainer, TrainerConfig
 from ..sharding.rules import Rules, make_rules
+from .compile_cache import use_compile_cache
 from .mesh import make_production_mesh
 
 
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the metrics-registry snapshot as JSON")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.bidir:
         args.overlap = True

@@ -113,22 +113,28 @@ def cached_decode_step(cfg: ModelConfig, rules: Rules):
 
 
 def greedy_generate(params, cfg: ModelConfig, rules: Rules, prompt,
-                    max_new: int = 16):
-    """Reference generation loop (examples/tests; small models only).
+                    max_new: int = 16, return_logits: bool = False):
+    """Reference generation loop.
 
     This is the oracle the serving engine is checked against: one request,
-    exact-length cache, greedy argmax at every step.
+    exact-length cache, greedy argmax at every step.  With
+    ``return_logits`` it also returns the (B, max_new, V) logits each
+    token was picked from, so a mismatch can be read as a near-tie or not.
     """
     B, S = prompt.shape
     cache = T.init_cache(cfg, B, S + max_new)
     cache, logits = cached_prefill_step(cfg, rules)(params, prompt, cache)
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-    out = [tok]
+    out, seen = [tok], [logits[:, None]]
     pos = jnp.full((B,), S, jnp.int32)
     step = cached_decode_step(cfg, rules)
     for _ in range(max_new - 1):
-        nxt, _, cache = step(params, tok, pos, cache)
+        nxt, logits, cache = step(params, tok, pos, cache)
         tok = nxt[:, None]
         out.append(tok)
+        seen.append(logits)
         pos = pos + 1
-    return jnp.concatenate(out, axis=1)
+    tokens = jnp.concatenate(out, axis=1)
+    if return_logits:
+        return tokens, jnp.concatenate(seen, axis=1)
+    return tokens

@@ -1,4 +1,3 @@
-import importlib.util
 import pathlib
 import sys
 
@@ -7,20 +6,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 SRC = ROOT / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
-
-# Property tests need `hypothesis`; hermetic containers may lack the dev
-# extra.  Rather than failing collection, install the deterministic
-# fallback shim (see tests/_hypothesis_fallback.py) under the real name.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", pathlib.Path(__file__).with_name(
-            "_hypothesis_fallback.py"))
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single CPU device.  Multi-device tests spawn subprocesses with

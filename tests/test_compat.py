@@ -1,7 +1,5 @@
 """repro.compat (version-adaptive jax surface) + core.collectives registry."""
 
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,26 +11,8 @@ from repro.core import collectives
 
 
 # ---------------------------------------------------------------------------
-# compat: resolution on the installed jax
+# compat: the installed jax's API through the one import point
 # ---------------------------------------------------------------------------
-
-def test_version_flags():
-    assert compat.JAX_VERSION == compat._version_tuple(jax.__version__)
-    assert len(compat.JAX_VERSION) == 3
-    assert compat.JAX_VERSION >= (0, 4, 0)
-    assert isinstance(compat.HAS_AXIS_TYPE, bool)
-    assert compat.HAS_AXIS_TYPE == hasattr(jax.sharding, "AxisType")
-    assert compat.SHARD_MAP_CHECK_KWARG in ("check_vma", "check_rep", None)
-
-
-def test_version_tuple_parsing():
-    assert compat._version_tuple("0.4.37") == (0, 4, 37)
-    assert compat._version_tuple("0.7.2.dev123") == (0, 7, 2)
-    assert compat._version_tuple("1.0") == (1, 0, 0)
-    # suffixed pieces keep only leading digits (37rc1 must not become 371)
-    assert compat._version_tuple("0.4.37rc1") == (0, 4, 37)
-    assert compat._version_tuple("0.5.dev0") == (0, 5, 0)
-
 
 def test_cost_analysis_normalizes_shapes():
     class _C:
@@ -44,8 +24,6 @@ def test_cost_analysis_normalizes_shapes():
                 raise self._ret
             return self._ret
 
-    assert compat.cost_analysis(_C([{"flops": 2.0}, {"bytes": 3.0}])) == \
-        {"flops": 2.0, "bytes": 3.0}                     # old jax: list
     assert compat.cost_analysis(_C({"flops": 2.0})) == {"flops": 2.0}
     assert compat.cost_analysis(_C(None)) == {}
     assert compat.cost_analysis(_C(RuntimeError("no cost model"))) == {}
@@ -67,69 +45,9 @@ def test_make_mesh_basic():
     mesh = compat.make_mesh((1,), ("data",), axis_types="auto")
     assert mesh.axis_names == ("data",)
     assert mesh.shape["data"] == 1
-
-
-# ---------------------------------------------------------------------------
-# compat: mocked old/new API shapes
-# ---------------------------------------------------------------------------
-
-def _fake_new_jax():
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return ("new", f, dict(mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=check_vma))
-    return types.SimpleNamespace(shard_map=shard_map)
-
-
-def _fake_old_jax():
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-        return ("old", f, dict(mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_rep))
-    return types.SimpleNamespace(
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=shard_map)))
-
-
-def test_resolve_shard_map_new_api():
-    impl, kw = compat._resolve_shard_map(_fake_new_jax())
-    assert kw == "check_vma"
-    wrapped = compat._build_shard_map(impl, kw)
-    tag, _, got = wrapped(lambda: None, mesh="m", in_specs=1, out_specs=2,
-                          check_vma=False)
-    assert tag == "new" and got["check_vma"] is False
-
-
-def test_resolve_shard_map_old_api_translates_kwarg():
-    impl, kw = compat._resolve_shard_map(_fake_old_jax())
-    assert kw == "check_rep"
-    wrapped = compat._build_shard_map(impl, kw)
-    tag, _, got = wrapped(lambda: None, mesh="m", in_specs=1, out_specs=2,
-                          check_vma=False)
-    assert tag == "old" and got["check_rep"] is False
-
-
-def test_resolve_shard_map_missing_raises():
-    with pytest.raises(ImportError):
-        compat._resolve_shard_map(types.SimpleNamespace(experimental=None))
-
-
-def test_resolve_axis_types_degrades():
-    if compat.HAS_AXIS_TYPE:
-        resolved = compat._resolve_axis_types("auto", 2)
-        assert resolved == (compat.AxisType.Auto,) * 2
-        with pytest.raises(ValueError):
-            compat._resolve_axis_types("bogus", 1)
-    else:
-        # jax <= 0.4.x: axis_types silently degrade to None (auto-only)
-        assert compat._resolve_axis_types("auto", 2) is None
-        assert compat._resolve_axis_types(None, 3) is None
-
-
-def test_mesh_from_devices_fallback():
-    devs = jax.devices()
-    mesh = compat._mesh_from_devices((1,), ("data",), devs)
-    assert mesh.axis_names == ("data",)
+    assert mesh.axis_types == (compat.AxisType.Auto,)
     with pytest.raises(ValueError):
-        compat._mesh_from_devices((len(devs) + 1,), ("data",), devs)
+        compat.make_mesh((1,), ("data",), axis_types="bogus")
 
 
 # ---------------------------------------------------------------------------
