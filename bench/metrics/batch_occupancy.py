@@ -1,0 +1,10 @@
+"""Scheduler: mean live decode rows over ``n_slots``, per decode step in the
+window, in percent (a fused stretch of k steps counts k times)."""
+
+
+def read(ctx):
+    steps = sum(k for _, k, _, _ in ctx.calls.decode)
+    if not steps:
+        return None
+    rows = sum(k * len(depths) for _, k, depths, _ in ctx.calls.decode)
+    return 100.0 * rows / (steps * ctx.n_slots)

@@ -1,0 +1,9 @@
+"""KV cache: mean of the page pool's ``occupancy`` (claimed pages over all
+pages), sampled at every decode step in the window, in percent."""
+
+
+def read(ctx):
+    steps = sum(k for _, k, _, _ in ctx.calls.decode)
+    if not steps:
+        return None
+    return 100.0 * sum(k * occ for _, k, _, occ in ctx.calls.decode) / steps

@@ -1,0 +1,9 @@
+"""Puts the repository root and ``src`` on the path for ``bench``'s tests."""
+
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
