@@ -367,12 +367,10 @@ def run_fixed_batch(params, cfg, rules, workload, slots: int
     # same derivation as the engine report (obs.metrics.throughput_summary):
     # the fixed batch contributes its useful fraction once per decode step
     from repro.obs import throughput_summary
-    out = throughput_summary(
+    return throughput_summary(
         useful_tokens=useful, wall_s=wall, ttfts_s=ttfts,
         occupancy_sum=(useful / raw) * decode_steps,
         decode_steps=decode_steps)
-    out.pop("decode_tokens_per_sec")   # the fixed path times no decode split
-    return out
 
 
 def main(argv=None) -> Dict:

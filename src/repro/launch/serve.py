@@ -32,11 +32,20 @@ onto the new plan on every kill/join; ``--min-alive K`` sets the
 graceful-degradation floor (the front-end rejects with a typed
 ``FleetDegraded`` + retry-after below it); ``--drain-deadline T``
 bounds the drain in ticks so a wedged schedule fails loud, never hangs.
+
+Observability: ``--trace-out`` writes the deterministic step-clock trace
+(``obs.Tracer``); ``--profile-dir DIR`` instead runs ``jax.profiler``
+around the serve run and writes its trace under DIR, where the engine's
+``serve.*`` host spans and the named scopes of its programs (``embed``,
+``attention``, ``kv_write``, ``mlp``, ``lm_head``, ``page_gather``,
+``page_scatter``) sit on one clock with the device's operations.  With
+both flags the engine's spans go to the ``--trace-out`` tracer only.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -199,6 +208,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "(open at ui.perfetto.dev)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the metrics-registry snapshot as JSON")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="run jax.profiler around the serve run and write "
+                         "its trace (device ops, the engine's serve.* "
+                         "spans) under DIR")
     args = ap.parse_args(argv)
     if ((args.kill_at or args.join_at or args.transient_at
          or args.checkpoint_every or args.drain_deadline) and not args.fleet):
@@ -300,12 +313,18 @@ def compare_to_oracle(params, cfg: ModelConfig, rules: Rules, workload,
 def main(argv=None):
     args = parse_args(argv)
     use_compile_cache()
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
     if args.fleet:
         cfg, rules, params = load_model(args)
         workload = build_workload(args, cfg.vocab_size)
-        return _serve_fleet(args, params, cfg, rules, workload)
-
-    run = serve(args)
+        with profile:
+            _serve_fleet(args, params, cfg, rules, workload)
+        return
+    with profile:
+        run = serve(args)
+    if args.profile_dir:
+        print(f"profile: {args.profile_dir}")
     engine, report, cfg = run.engine, run.report, run.cfg
     _write_obs(args, run.tracer, run.metrics)
 
@@ -317,10 +336,8 @@ def main(argv=None):
     print(f"prefill: {report.prefill_count} prompts, "
           f"{report.prefill_tokens} tokens in {report.prefill_wall:.2f}s  "
           f"(TTFT mean {report.ttft_mean*1e3:.0f}ms)")
-    print(f"decode:  {report.decode_tokens} tokens in "
-          f"{report.decode_wall:.2f}s "
-          f"({report.decode_tokens_per_sec:.1f} tok/s, "
-          f"occupancy {report.occupancy:.2f})")
+    print(f"decode:  {report.decode_tokens} tokens over "
+          f"{report.decode_steps} steps (occupancy {report.occupancy:.2f})")
     print(f"total:   {report.total_tokens} tokens in {report.wall:.2f}s "
           f"({report.tokens_per_sec:.1f} tok/s aggregate)")
     if args.paged:

@@ -290,16 +290,24 @@ def _attention_mix(x, p, cfg: ModelConfig, rules: Rules, positions,
     """
     B, S, d = x.shape
     hd, Hp, KVp = cfg.hd, cfg.h_padded, cfg.kv_param
-    h = rms_norm(x, p["ln1"] if "ln1" in p else p["ln_mix"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dk->bsk", h, p["wq"].astype(h.dtype)).reshape(B, S, Hp, hd)
-    k = jnp.einsum("bsd,dk->bsk", h, p["wk"].astype(h.dtype)).reshape(B, S, KVp, hd)
-    v = jnp.einsum("bsd,dk->bsk", h, p["wv"].astype(h.dtype)).reshape(B, S, KVp, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    q = shard(q, rules, "batch", None, "heads", None)
+    # named scopes (compiled-HLO op metadata, read back by a profile):
+    # "attention" around the projections, scores and output projection,
+    # "kv_write" around the cache insert — disjoint, never nested
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["ln1"] if "ln1" in p else p["ln_mix"],
+                     cfg.norm_eps)
+        q = jnp.einsum("bsd,dk->bsk", h,
+                       p["wq"].astype(h.dtype)).reshape(B, S, Hp, hd)
+        k = jnp.einsum("bsd,dk->bsk", h,
+                       p["wk"].astype(h.dtype)).reshape(B, S, KVp, hd)
+        v = jnp.einsum("bsd,dk->bsk", h,
+                       p["wv"].astype(h.dtype)).reshape(B, S, KVp, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        q = shard(q, rules, "batch", None, "heads", None)
 
     def _flash(q, k, v):
         KVf = cfg.kv_flash
@@ -330,66 +338,80 @@ def _attention_mix(x, p, cfg: ModelConfig, rules: Rules, positions,
         ring = window > 0 and Tc <= window
         if S == 1:  # decode: insert, then LBP-over-time attention
             wpos = pos % Tc if ring else pos
-            ck = jax.vmap(lambda c, kk, pp: jax.lax.dynamic_update_slice_in_dim(
-                c, kk, pp, 0))(ck, k[:, 0:1].astype(ck.dtype), wpos)
-            cv = jax.vmap(lambda c, vv, pp: jax.lax.dynamic_update_slice_in_dim(
-                c, vv, pp, 0))(cv, v[:, 0:1].astype(cv.dtype), wpos)
-            qg = q.reshape(B, S, KVp, Hp // KVp, hd)
-            # ring: every slot is inside the window by construction -> only
-            # the "not written yet" mask (t <= pos) applies.
-            o = decode_attention(qg, ck, cv, pos,
-                                 window=0 if ring else window)
-            o = o.reshape(B, S, Hp, hd)
+            with jax.named_scope("kv_write"):
+                ck = jax.vmap(
+                    lambda c, kk, pp: jax.lax.dynamic_update_slice_in_dim(
+                        c, kk, pp, 0))(ck, k[:, 0:1].astype(ck.dtype), wpos)
+                cv = jax.vmap(
+                    lambda c, vv, pp: jax.lax.dynamic_update_slice_in_dim(
+                        c, vv, pp, 0))(cv, v[:, 0:1].astype(cv.dtype), wpos)
+            with jax.named_scope("attention"):
+                qg = q.reshape(B, S, KVp, Hp // KVp, hd)
+                # ring: every slot is inside the window by construction ->
+                # only the "not written yet" mask (t <= pos) applies.
+                o = decode_attention(qg, ck, cv, pos,
+                                     window=0 if ring else window)
+                o = o.reshape(B, S, Hp, hd)
         else:       # prefill: write true-KV cache, attend with repeats
             from .tuning import TUNING
-            kc, vc = k, v
-            if TUNING.cache_write_constraint:
-                # match the cache's (batch, kv_time) layout before the
-                # insert: without this GSPMD falls back to involuntary full
-                # replication when resharding into the time-sharded cache.
-                kc = shard(kc, rules, "batch", "kv_time", None, None)
-                vc = shard(vc, rules, "batch", "kv_time", None, None)
-            if S >= Tc:   # windowed cache keeps the trailing Tc positions,
-                # rolled so slot == absolute_position % Tc (ring invariant
-                # for decode continuation; no-op when Tc divides S).
-                ck = jnp.roll(kc[:, S - Tc:], S % Tc, axis=1).astype(ck.dtype)
-                cv = jnp.roll(vc[:, S - Tc:], S % Tc, axis=1).astype(cv.dtype)
-            else:
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    ck, kc.astype(ck.dtype), 0, 1)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cv, vc.astype(cv.dtype), 0, 1)
-            o = _flash(q, k, v)
+            with jax.named_scope("kv_write"):
+                kc, vc = k, v
+                if TUNING.cache_write_constraint:
+                    # match the cache's (batch, kv_time) layout before the
+                    # insert: without this GSPMD falls back to involuntary
+                    # full replication when resharding into the
+                    # time-sharded cache.
+                    kc = shard(kc, rules, "batch", "kv_time", None, None)
+                    vc = shard(vc, rules, "batch", "kv_time", None, None)
+                if S >= Tc:   # windowed cache keeps the trailing Tc
+                    # positions, rolled so slot == absolute_position % Tc
+                    # (ring invariant for decode continuation; no-op when
+                    # Tc divides S).
+                    ck = jnp.roll(kc[:, S - Tc:], S % Tc,
+                                  axis=1).astype(ck.dtype)
+                    cv = jnp.roll(vc[:, S - Tc:], S % Tc,
+                                  axis=1).astype(cv.dtype)
+                else:
+                    ck = jax.lax.dynamic_update_slice_in_dim(
+                        ck, kc.astype(ck.dtype), 0, 1)
+                    cv = jax.lax.dynamic_update_slice_in_dim(
+                        cv, vc.astype(cv.dtype), 0, 1)
+            with jax.named_scope("attention"):
+                o = _flash(q, k, v)
         new_kv = (ck, cv)
     else:
-        o = _flash(q, k, v)
-    o = shard(o, rules, "batch", None, "heads", None)
-    # LBP row-parallel out-projection: contraction over model-sharded heads.
-    from . import lbp_linear
-    from .tuning import reduce_pref_dtype
-    if lbp_linear.applicable(rules):
-        y = lbp_linear.lbp_row_parallel(
-            o.reshape(B, S, Hp * hd).astype(x.dtype),
-            p["wo"].astype(x.dtype), rules)
-        return y, new_kv
-    y = jnp.einsum("bshk,hkD->bsD", o.astype(x.dtype),
-                   p["wo"].reshape(Hp, hd, d).astype(x.dtype),
-                   preferred_element_type=reduce_pref_dtype(x.dtype))
-    return shard(y.astype(x.dtype), rules, "batch", "seq", None), new_kv
+        with jax.named_scope("attention"):
+            o = _flash(q, k, v)
+    with jax.named_scope("attention"):
+        o = shard(o, rules, "batch", None, "heads", None)
+        # LBP row-parallel out-projection: contraction over model-sharded
+        # heads.
+        from . import lbp_linear
+        from .tuning import reduce_pref_dtype
+        if lbp_linear.applicable(rules):
+            y = lbp_linear.lbp_row_parallel(
+                o.reshape(B, S, Hp * hd).astype(x.dtype),
+                p["wo"].astype(x.dtype), rules)
+            return y, new_kv
+        y = jnp.einsum("bshk,hkD->bsD", o.astype(x.dtype),
+                       p["wo"].reshape(Hp, hd, d).astype(x.dtype),
+                       preferred_element_type=reduce_pref_dtype(x.dtype))
+        return shard(y.astype(x.dtype), rules, "batch", "seq", None), new_kv
 
 
 def _ffn_mix(x, p, cfg: ModelConfig, rules: Rules, prefix=""):
     """Pre-norm FFN (dense SwiGLU or MoE). Returns (y, aux)."""
-    ln = p["ln2"] if "ln2" in p else p["ln_mlp"]
-    h = rms_norm(x, ln, cfg.norm_eps)
-    if cfg.is_moe:
-        return moe_ffn(h, p[prefix + "router"], p[prefix + "w_gate"],
-                       p[prefix + "w_up"], p[prefix + "w_down"], rules,
-                       experts_per_token=cfg.experts_per_token,
-                       capacity_factor=cfg.capacity_factor)
-    y = swiglu_ffn(h, p[prefix + "w_gate"], p[prefix + "w_up"],
-                   p[prefix + "w_down"], rules)
-    return y, jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        ln = p["ln2"] if "ln2" in p else p["ln_mlp"]
+        h = rms_norm(x, ln, cfg.norm_eps)
+        if cfg.is_moe:
+            return moe_ffn(h, p[prefix + "router"], p[prefix + "w_gate"],
+                           p[prefix + "w_up"], p[prefix + "w_down"], rules,
+                           experts_per_token=cfg.experts_per_token,
+                           capacity_factor=cfg.capacity_factor)
+        y = swiglu_ffn(h, p[prefix + "w_gate"], p[prefix + "w_up"],
+                       p[prefix + "w_down"], rules)
+        return y, jnp.zeros((), jnp.float32)
 
 
 # ===========================================================================
@@ -602,7 +624,8 @@ def prefill(params, cfg: ModelConfig, rules: Rules, tokens, cache,
     unpadded length-``last_index[r]+1`` prefill.
     """
     B = tokens.shape[0]
-    x = embed_tokens(tokens, params["embed"], rules)
+    with jax.named_scope("embed"):
+        x = embed_tokens(tokens, params["embed"], rules)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     S = x.shape[1]
@@ -610,28 +633,31 @@ def prefill(params, cfg: ModelConfig, rules: Rules, tokens, cache,
     x = shard(x, rules, "batch", "seq", None)
     x, cache = _stack_with_cache(x, params, cfg, rules, positions, cache,
                                  pos=None)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if last_index is None:
-        last = x[:, -1]
-    else:
-        last = x[jnp.arange(B), jnp.asarray(last_index, jnp.int32)]
-    logits = jnp.einsum("bd,vd->bv", last.astype(jnp.float32),
-                        params["embed"].astype(jnp.float32))
-    return cache, shard(logits, rules, "batch", "vocab")
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if last_index is None:
+            last = x[:, -1]
+        else:
+            last = x[jnp.arange(B), jnp.asarray(last_index, jnp.int32)]
+        logits = jnp.einsum("bd,vd->bv", last.astype(jnp.float32),
+                            params["embed"].astype(jnp.float32))
+        return cache, shard(logits, rules, "batch", "vocab")
 
 
 def decode_step(params, cfg: ModelConfig, rules: Rules, token, pos, cache):
     """One token: token (B, 1) int32, pos (B,) int32 -> (logits, cache)."""
     B = token.shape[0]
-    x = embed_tokens(token, params["embed"], rules)
+    with jax.named_scope("embed"):
+        x = embed_tokens(token, params["embed"], rules)
     positions = pos[:, None]
     x = shard(x, rules, "batch", None, None)
     x, cache = _stack_with_cache(x, params, cfg, rules, positions, cache,
                                  pos=pos)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
-                        params["embed"].astype(jnp.float32))
-    return shard(logits, rules, "batch", None, "vocab"), cache
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
+                            params["embed"].astype(jnp.float32))
+        return shard(logits, rules, "batch", None, "vocab"), cache
 
 
 def _stack_with_cache(x, params, cfg, rules, positions, cache, pos):

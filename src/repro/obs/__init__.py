@@ -8,7 +8,9 @@ predictions:
   trace.py    ``Tracer``: nested spans + instant events + counter tracks
               against an INJECTABLE clock (engine steps, controller ticks,
               ``ManualClock`` seconds — never the wall clock), with a
-              ``NullTracer`` no-op default so hot loops pay one method call.
+              ``NullTracer`` no-op default so hot loops pay one method call;
+              ``ProfilerTracer`` puts the same hooks on ``jax.profiler``'s
+              clock (the serving engine's default).
   export.py   Chrome-trace/Perfetto JSON exporter (byte-deterministic for
               deterministic runs).
   metrics.py  process-local registry of counters / gauges / fixed-bucket
@@ -34,4 +36,4 @@ from .drift import DriftMonitor, drift_fractions  # noqa: F401
 from .export import to_chrome_json, write_chrome_trace  # noqa: F401
 from .metrics import (Counter, Gauge, Histogram,  # noqa: F401
                       MetricsRegistry, throughput_summary)
-from .trace import NullTracer, Tracer  # noqa: F401
+from .trace import NullTracer, ProfilerTracer, Tracer  # noqa: F401

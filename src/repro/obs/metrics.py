@@ -179,8 +179,7 @@ class MetricsRegistry:
 
 def throughput_summary(*, useful_tokens: int, wall_s: float,
                        ttfts_s: Iterable[float],
-                       occupancy_sum: float, decode_steps: int,
-                       decode_tokens: int = 0, decode_wall_s: float = 0.0
+                       occupancy_sum: float, decode_steps: int
                        ) -> Dict[str, float]:
     """The one tok/s + TTFT + occupancy derivation.
 
@@ -191,7 +190,6 @@ def throughput_summary(*, useful_tokens: int, wall_s: float,
     ttfts: List[float] = [float(t) for t in ttfts_s]
     return {
         "tokens_per_sec": useful_tokens / max(wall_s, 1e-9),
-        "decode_tokens_per_sec": decode_tokens / max(decode_wall_s, 1e-9),
         "ttft_mean_s": (sum(ttfts) / len(ttfts)) if ttfts else 0.0,
         "occupancy": (occupancy_sum / decode_steps) if decode_steps else 0.0,
         "useful_tokens": int(useful_tokens),

@@ -14,10 +14,18 @@ spans, a ``membership`` lane for kill/join).  Spans that stay open across
 engine iterations (queue-wait, a request's whole decode residency) are
 keyed: ``begin(..., key=...)`` then ``end(key)`` from a later step.
 
-``NullTracer`` is the default everywhere: every hook in a hot loop costs
-exactly one no-op method call and allocates nothing — the engine's
-dispatch count with tracing on equals the count with it off (tested),
-because hooks only read host-side state the loop already owns.
+``NullTracer`` is the default everywhere except the serving engine: every
+hook in a hot loop costs exactly one no-op method call and allocates
+nothing — the engine's dispatch count with tracing on equals the count
+with it off (tested), because hooks only read host-side state the loop
+already owns.
+
+``ProfilerTracer`` (the serving engine's default) takes the same hooks
+onto the profiler's clock: each becomes a ``jax.profiler.TraceAnnotation``
+named ``serve.<name>`` with the hook's arguments as its stats, so a device
+trace taken with ``jax.profiler`` shows the engine's host work on the same
+timeline as the device programs.  Without a profiler session each hook is
+one inactive TraceMe.
 """
 
 from __future__ import annotations
@@ -25,7 +33,9 @@ from __future__ import annotations
 import contextlib
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["Tracer", "NullTracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "NullTracer", "ProfilerTracer"]
 
 
 def _jsonable(v: Any) -> Any:
@@ -163,3 +173,62 @@ class NullTracer:
 
     def __len__(self) -> int:
         return 0
+
+
+class ProfilerTracer:
+    """The tracer hooks as ``jax.profiler`` host annotations.
+
+    A span is a ``TraceAnnotation`` named ``serve.<name>`` whose keyword
+    arguments become the event's stats; a keyed span holds its annotation
+    open from ``begin`` until ``end`` (which may add stats); events and
+    counters are zero-length annotations (a counter's value is its
+    ``value`` stat).  ``track`` and ``lane`` only lay out the
+    deterministic export, and are dropped.  There is no timeline of its
+    own: the profiler's clock is the timeline.
+    """
+
+    enabled = True
+    clock = None
+    prefix = "serve."
+
+    def __init__(self):
+        self._open: Dict[Any, Any] = {}
+        self._auto = 0
+
+    def use_clock(self, fn) -> None:
+        pass
+
+    def event(self, name: str, *, track=None, lane=None, **args) -> None:
+        with TraceAnnotation(self.prefix + name, **args):
+            pass
+
+    def begin(self, name: str, *, track=None, lane=None, key: Any = None,
+              **args) -> Any:
+        if key is None:
+            self._auto += 1
+            key = ("__auto__", self._auto)
+        if key in self._open:
+            self.end(key)
+        span = TraceAnnotation(self.prefix + name, **args)
+        span.__enter__()
+        self._open[key] = (name, span)
+        return key
+
+    def end(self, key: Any, **args) -> None:
+        opened = self._open.pop(key, None)
+        if opened is None:
+            return
+        span = opened[1]
+        if args:
+            span.set_metadata(**args)
+        span.__exit__(None, None, None)
+
+    def span(self, name: str, *, track=None, lane=None, **args):
+        return TraceAnnotation(self.prefix + name, **args)
+
+    def counter(self, name: str, value: float, *, track=None) -> None:
+        with TraceAnnotation(self.prefix + name, value=value):
+            pass
+
+    def open_spans(self) -> List[str]:
+        return [name for name, _ in self._open.values()]

@@ -538,7 +538,8 @@ def gather_page_view(pool_tree, table):
                             jax.numpy.zeros((), g.dtype), g)
         L, S, npp, ps = g.shape[:4]
         return g.reshape(L, S, npp * ps, *g.shape[4:])
-    return jax.tree_util.tree_map(gather, pool_tree)
+    with jax.named_scope("page_gather"):
+        return jax.tree_util.tree_map(gather, pool_tree)
 
 
 def scatter_page_view(pool_tree, view_tree, table):
@@ -561,4 +562,5 @@ def scatter_page_view(pool_tree, view_tree, table):
                                             pages.ndim),
                                 jax.numpy.zeros((), pages.dtype), pages)
         return leaf.at[:, table].set(pages)
-    return jax.tree_util.tree_map(scatter, pool_tree, view_tree)
+    with jax.named_scope("page_scatter"):
+        return jax.tree_util.tree_map(scatter, pool_tree, view_tree)

@@ -42,7 +42,7 @@ from ...models import transformer as T
 from ...models.config import ModelConfig
 from ...obs import clock as obs_clock
 from ...obs.metrics import MetricsRegistry, throughput_summary
-from ...obs.trace import NullTracer
+from ...obs.trace import NullTracer, ProfilerTracer
 from ...sharding.rules import Rules
 from .cache_pool import PagedCachePool, SlotCachePool, write_slot
 from .queue import AdmissionError, AdmissionLimits, RequestQueue
@@ -74,6 +74,9 @@ class TransformerModel:
         self.params = params
         self.cfg = cfg
         self.rules = rules
+        # host spans of the adapter's own work (the engine hands over its
+        # tracer when it is built around this adapter)
+        self.tracer = NullTracer()
         self._decode_step = make_decode_step(cfg, rules)
 
         def group_prefill(cache_len, params, tokens, lengths, slots, pool,
@@ -90,7 +93,8 @@ class TransformerModel:
             batch = T.init_cache(cfg, B, cache_len)
             batch, logits = T.prefill(params, cfg, rules, tokens, batch,
                                       last_index=lengths - 1)
-            firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             for b in range(B):   # static unroll: B is a compile-time const
                 row = jax.tree_util.tree_map(
                     lambda c: jax.lax.dynamic_slice_in_dim(c, b, 1, axis=1),
@@ -224,7 +228,8 @@ class PagedTransformerModel(TransformerModel):
             batch = T.init_cache(self.cfg, B, view_len)
             batch, logits = T.prefill(params, self.cfg, self.rules, tokens,
                                       batch, last_index=lengths - 1)
-            firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                firsts = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             for b in range(B):   # static unroll: B is a compile-time const
                 row = jax.tree_util.tree_map(
                     lambda c: jax.lax.dynamic_slice_in_dim(c, b, 1, axis=1),
@@ -260,8 +265,9 @@ class PagedTransformerModel(TransformerModel):
         # over the host numpy buffer, and the allocator mutates the page
         # maps in place while the previous async dispatch may still be
         # reading them — without the copies the maps race the device
-        return (jnp.asarray(self._paged.table.copy()),
-                jnp.asarray(self._paged.write_table.copy()))
+        with self.tracer.span("page_tables", lane="engine"):
+            return (jnp.asarray(self._paged.table.copy()),
+                    jnp.asarray(self._paged.write_table.copy()))
 
     def prefill(self, pool, prompts, slots, tok, pos):
         assert self._paged is not None, "init_paged_pool must run first"
@@ -275,11 +281,12 @@ class PagedTransformerModel(TransformerModel):
         # pages are trash there, so a follower's recomputed prefix KV is
         # discarded and the creator's pages are never overwritten (the
         # fancy index copies — no alias of the live host map)
-        tables = self._paged.write_table[slots_np]  # (B, pages_per_slot)
+        with self.tracer.span("page_tables", lane="engine"):
+            tables = jnp.asarray(self._paged.write_table[slots_np])
         return self._paged_prefill(self._paged.view_len, self.params,
                                    jnp.asarray(batch), jnp.asarray(lengths),
-                                   jnp.asarray(slots_np),
-                                   jnp.asarray(tables), pool, tok, pos)
+                                   jnp.asarray(slots_np), tables, pool,
+                                   tok, pos)
 
     def decode(self, pool, tok, pos):
         table, write_table = self._tables()
@@ -365,7 +372,6 @@ class EngineReport:
     ttft: Dict[int, float]                 # rid -> seconds to first token
     wall: float
     prefill_wall: float
-    decode_wall: float
     page_occupancy: float = 0.0            # mean used/total pages (paged only)
 
     @property
@@ -376,10 +382,6 @@ class EngineReport:
     @property
     def tokens_per_sec(self) -> float:
         return self.total_tokens / max(self.wall, 1e-9)
-
-    @property
-    def decode_tokens_per_sec(self) -> float:
-        return self.decode_tokens / max(self.decode_wall, 1e-9)
 
     @property
     def ttft_mean(self) -> float:
@@ -394,9 +396,7 @@ class EngineReport:
             useful_tokens=self.total_tokens, wall_s=self.wall,
             ttfts_s=self.ttft.values(),
             occupancy_sum=self.occupancy * self.decode_steps,
-            decode_steps=self.decode_steps,
-            decode_tokens=self.decode_tokens,
-            decode_wall_s=self.decode_wall)
+            decode_steps=self.decode_steps)
         out.update(steps=self.steps, prefill_count=self.prefill_count,
                    n_completed=len(self.completed),
                    page_occupancy=self.page_occupancy)
@@ -415,8 +415,11 @@ class ServingEngine:
         self.config = config
         self.name = name
         # observability plane (host-side only — hooks never add a jitted
-        # dispatch; the NullTracer default makes every hook one no-op)
-        self.tracer = tracer if tracer is not None else NullTracer()
+        # dispatch; the ProfilerTracer default makes every hook one
+        # inactive TraceMe unless a jax.profiler session is running)
+        self.tracer = tracer if tracer is not None else ProfilerTracer()
+        if hasattr(model, "tracer"):
+            model.tracer = self.tracer
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.queue = RequestQueue(AdmissionLimits(
             max_prompt_len=config.max_prompt_len,
@@ -470,8 +473,7 @@ class ServingEngine:
             self.tracer.use_clock(lambda: self.clock)
         self._stats = dict(decode_steps=0, prefill_count=0, decode_tokens=0,
                            prefill_tokens=0, occupancy_sum=0.0,
-                           prefill_wall=0.0, decode_wall=0.0,
-                           page_occupancy_sum=0.0)
+                           prefill_wall=0.0, page_occupancy_sum=0.0)
 
     def _now(self) -> float:
         """Engine time in arrival units (seconds since run start in
@@ -513,11 +515,24 @@ class ServingEngine:
         if not self.scheduler.has_work:
             return False
         self.steps += 1
+        shape = {}
+        key = self.tracer.begin("step", track=self.name, lane="engine",
+                                step=self.steps)
+        try:
+            shape = self._iterate()
+        finally:
+            self.tracer.end(key, **shape)
+        return True
+
+    def _iterate(self) -> Dict[str, int]:
+        """The body of ``step``; returns what it ran (admitted requests,
+        live decode rows, fused decode steps) for the step's span."""
         if self._wall_arrivals:
             self.clock = self._now()
         now, wall = self.clock, time.perf_counter()
         self.queue.mark_eligible(now, wall)
-        plan = self.scheduler.plan(now)
+        with self.tracer.span("plan", track=self.name, lane="engine"):
+            plan = self.scheduler.plan(now)
         if not (plan.retired or plan.admit or self.scheduler.active):
             # nothing in flight and nothing eligible: fast-forward the
             # clock to the next arrival instead of spinning no-op steps
@@ -529,7 +544,7 @@ class ServingEngine:
                     self.clock = self._now()
                 else:
                     self.clock = float(nxt)
-                return True
+                return dict(n_admit=0, n_live=0, k=0)
         for r in plan.retired:
             r.finish_wall = r.finish_wall or wall
             self.completed[r.rid] = r
@@ -541,19 +556,25 @@ class ServingEngine:
         if plan.admit:
             for r in plan.admit:
                 self.tracer.end(("qw", self.name, r.rid))
-                self.tracer.begin("serve", track=self.name,
+                self.tracer.begin("request", track=self.name,
                                   lane=f"req:{r.rid}",
                                   key=("req", self.name, r.rid),
                                   rid=r.rid, prompt_len=r.prompt_len,
                                   max_new=r.max_new, slot=r.slot)
-            pf_key = self.tracer.begin("prefill", track=self.name,
-                                       lane="engine", n=len(plan.admit))
+            prompts = [r.prompt for r in plan.admit]
             t0 = time.perf_counter()
-            self.cache, firsts, self._tok, self._pos = self.model.prefill(
-                self.cache, [r.prompt for r in plan.admit],
-                [r.slot for r in plan.admit], self._tok, self._pos)
+            with self.tracer.span("prefill", track=self.name, lane="engine",
+                                  n=len(prompts),
+                                  tokens=sum(len(p) for p in prompts),
+                                  padded_len=max(len(p) for p in prompts)):
+                self.cache, firsts, self._tok, self._pos = self.model.prefill(
+                    self.cache, prompts, [r.slot for r in plan.admit],
+                    self._tok, self._pos)
             if hasattr(firsts, "block_until_ready"):
-                firsts.block_until_ready()  # TTFT is a real latency metric
+                with self.tracer.span("prefill_wait", track=self.name,
+                                      lane="engine"):
+                    # TTFT is a real latency metric
+                    firsts.block_until_ready()
             t1 = time.perf_counter()
             for b, r in enumerate(plan.admit):
                 r.first_token = (firsts, b)   # sliced lazily at drain
@@ -570,7 +591,6 @@ class ServingEngine:
                     r.first_token_wall - r.eligible_wall)
             self._stats["prefill_count"] += len(plan.admit)
             self._stats["prefill_wall"] += t1 - t0
-            self.tracer.end(pf_key)
             self.metrics.counter("prefill_tokens").inc(
                 sum(r.prompt_len for r in plan.admit))
             # the prefill dispatch above wrote these requests' prompt
@@ -584,6 +604,7 @@ class ServingEngine:
         # admits their first (and only) token — drop the already-done ones
         # so budget math (k, page growth, token accounting) can't overshoot
         live = [r for r in plan.decode if not r.done]
+        k = 0
         if live:
             # decode fusion: when nothing was admitted this step AND no
             # admission can happen before the next retirement (queue empty,
@@ -600,25 +621,28 @@ class ServingEngine:
             # paged plane: claim every page the next k steps will write
             # BEFORE the dispatch (the page map is an argument of the
             # fused call); reservations make the claims infallible
-            self.pool.prepare_decode(live, k)
-            dk_key = self.tracer.begin("decode", track=self.name,
-                                       lane="engine", k=k, batch=len(live))
-            t0 = time.perf_counter()
+            with self.tracer.span("prepare_decode", track=self.name,
+                                  lane="engine", k=k, rows=len(live)):
+                self.pool.prepare_decode(live, k)
+            paged = isinstance(self.pool, PagedCachePool)
+            dk_key = self.tracer.begin(
+                "decode", track=self.name, lane="engine", k=k,
+                rows=len(live),
+                depth_sum=sum(r.prompt_len + r.n_generated for r in live),
+                pages_used=self.pool.used_pages if paged else 0)
             self.cache, rows, self._tok, self._pos = self.model.decode_multi(
                 self.cache, self._tok, self._pos, k)
             self._trace.append(rows)       # (k, n_slots)
             self._rows += k
             for r in live:
                 r.n_generated += k
-            t1 = time.perf_counter()
             self._stats["decode_steps"] += k
             self._stats["decode_tokens"] += k * len(live)
             self._stats["occupancy_sum"] += (k * len(live)
                                              / self.config.n_slots)
-            if isinstance(self.pool, PagedCachePool):
+            if paged:
                 self._stats["page_occupancy_sum"] += (
                     k * self.pool.used_pages / self.pool.n_pages)
-            self._stats["decode_wall"] += t1 - t0
             self.metrics.counter("decode_tokens").inc(k * len(live))
         if not self._wall_arrivals:   # wall mode reads the clock per step
             self.clock += float(max(k, 1) if live else 1)
@@ -631,7 +655,9 @@ class ServingEngine:
         self.metrics.gauge("queue_depth").set(len(self.queue))
         self.metrics.gauge("pool_occupancy").set(self.pool.occupancy)
         self.tracer.counter("queue_depth", len(self.queue), track=self.name)
-        return True
+        self.tracer.counter("pool_occupancy", self.pool.occupancy,
+                            track=self.name)
+        return dict(n_admit=len(plan.admit), n_live=len(live), k=k)
 
     # -- host materialization (incremental: the fleet drain surface) ----
     def _trace_upto(self, rows: int) -> np.ndarray:
@@ -641,9 +667,19 @@ class ServingEngine:
         if self._host_trace.shape[0] < rows:
             pend = self._trace[self._fetched_blocks:]
             if pend:
-                got = np.asarray(jax.device_get(
-                    jnp.concatenate(pend) if len(pend) > 1 else pend[0]))
-                self._host_trace = np.concatenate([self._host_trace, got])
+                block = pend[0]
+                if len(pend) > 1:
+                    with self.tracer.span("join_tokens", track=self.name,
+                                          lane="engine", blocks=len(pend)):
+                        block = jnp.concatenate(pend)
+                with self.tracer.span("fetch_tokens", track=self.name,
+                                      lane="engine", blocks=len(pend),
+                                      rows=block.shape[0]):
+                    got = np.asarray(jax.device_get(block))
+                with self.tracer.span("join_tokens", track=self.name,
+                                      lane="engine", blocks=len(pend)):
+                    self._host_trace = np.concatenate([self._host_trace,
+                                                       got])
                 self._fetched_blocks = len(self._trace)
         return self._host_trace
 
@@ -655,8 +691,10 @@ class ServingEngine:
         firsts, b = r.first_token
         group = self._firsts_cache.get(id(firsts))
         if group is None:
-            group = self._firsts_cache[id(firsts)] = np.asarray(
-                jax.device_get(firsts))
+            with self.tracer.span("fetch_firsts", track=self.name,
+                                  lane="engine"):
+                group = self._firsts_cache[id(firsts)] = np.asarray(
+                    jax.device_get(firsts))
         return group[b:b + 1]
 
     def harvest(self) -> Dict[int, np.ndarray]:
@@ -669,6 +707,7 @@ class ServingEngine:
         nothing when nothing finished.
         """
         out: Dict[int, np.ndarray] = {}
+        key = self.tracer.begin("harvest", track=self.name, lane="engine")
         for rid, r in self.completed.items():
             if rid in self.results:
                 continue
@@ -679,6 +718,7 @@ class ServingEngine:
             r.tokens = np.concatenate([self._firsts(r), dec]).astype(np.int32)
             out[rid] = r.tokens
         self.results.update(out)
+        self.tracer.end(key, n_done=len(out))
         return out
 
     def tokens_so_far(self, rid: int) -> np.ndarray:
@@ -770,8 +810,7 @@ class ServingEngine:
             decode_tokens=s["decode_tokens"],
             prefill_tokens=s["prefill_tokens"],
             occupancy=occ, ttft=ttft, wall=wall,
-            prefill_wall=s["prefill_wall"], decode_wall=s["decode_wall"],
-            page_occupancy=pocc)
+            prefill_wall=s["prefill_wall"], page_occupancy=pocc)
 
 
 def serve_requests(params, cfg: ModelConfig, rules: Rules, requests,

@@ -40,7 +40,8 @@ def make_prefill_step(cfg: ModelConfig, rules: Rules):
 def make_decode_step(cfg: ModelConfig, rules: Rules):
     def step(params, token, pos, cache):
         logits, cache = T.decode_step(params, cfg, rules, token, pos, cache)
-        next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_token, logits, cache
     return step
 

@@ -20,8 +20,9 @@ from repro.fleet import (FaultPlan, FleetClosed, FleetController,
                          FleetFrontend, Replica, UnknownRequest,
                          build_engine)
 from repro.obs import (DriftMonitor, Histogram, MetricsRegistry, NullTracer,
-                       Tracer, drift_fractions, throughput_summary,
-                       to_chrome_json, write_chrome_trace)
+                       ProfilerTracer, Tracer, drift_fractions,
+                       throughput_summary, to_chrome_json,
+                       write_chrome_trace)
 from repro.serve.engine import AdmissionError, EngineConfig, synthetic_workload
 from repro.serve.engine.planner import CapacityPlanner
 from test_serve_engine import FakeModel
@@ -186,16 +187,46 @@ def run_counting_engine(tracer):
     return model.dispatches, rep
 
 
-def test_tracing_adds_zero_dispatches():
+def test_tracing_adds_zero_dispatches(tmp_path):
+    import jax
     d_null, rep_null = run_counting_engine(NullTracer())
     tr = Tracer()
-    d_traced, rep_traced = run_counting_engine(tr)
-    assert d_traced == d_null
-    assert rep_traced.steps == rep_null.steps
-    for rid in rep_null.completed:
-        np.testing.assert_array_equal(rep_null.completed[rid],
-                                      rep_traced.completed[rid])
+    runs = [run_counting_engine(tr), run_counting_engine(ProfilerTracer())]
+    with jax.profiler.trace(str(tmp_path)):       # annotations recording
+        runs.append(run_counting_engine(ProfilerTracer()))
+    for d_traced, rep_traced in runs:
+        assert d_traced == d_null
+        assert rep_traced.steps == rep_null.steps
+        for rid in rep_null.completed:
+            np.testing.assert_array_equal(rep_null.completed[rid],
+                                          rep_traced.completed[rid])
     assert len(tr.events) > 0
+
+
+def test_profiler_tracer_hooks_become_annotations(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    tr = ProfilerTracer()
+    assert tr.enabled and tr.clock is None
+    with jax.profiler.trace(str(tmp_path)):
+        key = tr.begin("request", track="e", lane="req:1", key=("r", 1),
+                       rid=1)
+        with tr.span("step", track="e", lane="engine", step=3):
+            tr.event("retire", rid=1)
+            tr.counter("queue_depth", 2, track="e")
+        assert tr.open_spans() == ["request"]
+        tr.end(key, tokens=7)
+        tr.end(key)                               # closed: no-op
+    assert tr.open_spans() == []
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    got = {e.name: dict(e.stats)
+           for plane in ProfileData.from_file(str(path)).planes
+           for line in plane.lines for e in line.events
+           if e.name.startswith("serve.")}
+    assert got == {"serve.request": {"rid": 1, "tokens": 7},
+                   "serve.step": {"step": 3},
+                   "serve.retire": {"rid": 1},
+                   "serve.queue_depth": {"value": 2}}
 
 
 # ---------------------------------------------------------------------------
@@ -219,10 +250,11 @@ def test_engine_spans_and_rejection_metrics():
     assert reg.counter_value("requests_retired") == 1
     names = [(e["ph"], e["name"]) for e in tr.events
              if e["lane"] == f"req:{rid}"]
-    # queue-wait opens at submit, closes at admit; serve spans admit->retire
+    # queue-wait opens at submit, closes at admit; request spans
+    # admit->retire
     assert names[0] == ("B", "queue_wait")
-    assert ("E", "queue_wait") in names and ("B", "serve") in names
-    assert names[-2:] == [("E", "serve"), ("i", "retire")]
+    assert ("E", "queue_wait") in names and ("B", "request") in names
+    assert names[-2:] == [("E", "request"), ("i", "retire")]
     # TTFT is observed into the fixed-bucket histogram
     assert reg.histogram("ttft_s").n == 1
     snap = reg.snapshot()
@@ -240,8 +272,7 @@ def test_engine_report_as_dict_matches_throughput_summary():
         useful_tokens=rep.total_tokens, wall_s=rep.wall,
         ttfts_s=rep.ttft.values(),
         occupancy_sum=rep.occupancy * rep.decode_steps,
-        decode_steps=rep.decode_steps, decode_tokens=rep.decode_tokens,
-        decode_wall_s=rep.decode_wall)
+        decode_steps=rep.decode_steps)
     for k, v in ref.items():
         assert d[k] == v, k
     assert d["tokens_per_sec"] == rep.tokens_per_sec
