@@ -4,6 +4,9 @@
                        as K-grid steps with a VMEM accumulator)
   flash_attention_kernel  blocked online-softmax attention (KV blocks as layers)
   rglru_kernel         RG-LRU gated linear recurrence (recurrentgemma)
+  paged_decode_attention_kernel  decode attention read in place from the
+                       paged KV pool through the page table (one layer,
+                       the live pages of each row only)
   slstm_kernel         weight-stationary sLSTM (recurrent R matrices VMEM-
                        resident across the time loop — kills the per-step
                        HBM weight re-reads that dominate xlstm's roofline)

@@ -9,6 +9,11 @@ Every wrapper:
     from the backend, so a missing chip fails instead of silently
     interpreting;
   * has a pure-jnp oracle in ref.py used by the test sweeps.
+
+``paged_decode_attention`` is the one selector among them: the serving
+path calls it inside its own jitted step, and the lowering platform picks
+the kernel (TPU) or its ref.py oracle (everywhere else); it takes no
+``interpret`` flag.
 """
 
 from __future__ import annotations
@@ -20,6 +25,8 @@ import jax.numpy as jnp
 
 from .flash_attention_kernel import flash_attention_pallas
 from .lbp_matmul_kernel import lbp_matmul_pallas
+from .paged_decode_attention_kernel import paged_decode_attention_pallas
+from .ref import paged_decode_attention_ref
 from .rglru_kernel import rglru_pallas
 
 
@@ -147,3 +154,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                  block_q=block_q, block_k=block_k,
                                  interpret=interpret)
     return out[:, :S].reshape(B, H, S, D)
+
+
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, layer, table: jax.Array,
+                           pos: jax.Array) -> jax.Array:
+    """One layer's decode attention over the page pool (see
+    ``paged_decode_attention_kernel``): the Pallas kernel where the program
+    is lowered for a TPU and the head size fills the lanes, the XLA
+    reference (``ref.paged_decode_attention_ref``) everywhere else.  The
+    lowering picks the branch (``lax.platform_dependent``); nothing here
+    asks which backend is present."""
+    args = (q, k_pool, v_pool, layer, table, pos)
+    if q.shape[-1] % 128:      # the kernel's tiles need whole lane rows
+        return paged_decode_attention_ref(*args)
+    return jax.lax.platform_dependent(
+        *args, tpu=paged_decode_attention_pallas,
+        default=paged_decode_attention_ref)

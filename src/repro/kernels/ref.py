@@ -66,3 +66,26 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bst,btd->bsd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def paged_decode_attention_ref(q, k_pool, v_pool, layer, table, pos):
+    """One layer's decode attention over the page pool, through XLA.
+
+    Same signature as ``paged_decode_attention_pallas``: q (S, KV, G, hd),
+    pools (L, n_pages + 1, page_size, KV, hd), ``layer`` an int32 scalar,
+    ``table`` (S, pages_per_slot) the READ map, ``pos`` (S,).  Gathers the
+    layer's pages of each row, maps trash-page entries to zeros, and runs
+    ``models.attention.decode_attention`` over positions ``0..pos``."""
+    from ..models.attention import decode_attention
+    S, npp = table.shape
+    trash = k_pool.shape[1] - 1
+
+    def view(pool):
+        g = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+        g = g[table]                                  # (S, npp, ps, KV, hd)
+        g = jnp.where((table == trash)[:, :, None, None, None],
+                      jnp.zeros((), g.dtype), g)
+        return g.reshape(S, npp * g.shape[2], *g.shape[3:])
+
+    o = decode_attention(q[:, None], view(k_pool), view(v_pool), pos)
+    return o[:, 0]

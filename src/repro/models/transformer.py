@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.ops import paged_decode_attention
 from ..sharding.rules import Rules, shard
 from .attention import decode_attention, flash_attention_xla
 from .config import ModelConfig
@@ -288,26 +289,12 @@ def _attention_mix(x, p, cfg: ModelConfig, rules: Rules, positions,
         contraction (flash-decoding).  Aggregation = the small all-reduces
         GSPMD emits for the T-reductions.
     """
-    B, S, d = x.shape
+    B, S, _ = x.shape
     hd, Hp, KVp = cfg.hd, cfg.h_padded, cfg.kv_param
     # named scopes (compiled-HLO op metadata, read back by a profile):
     # "attention" around the projections, scores and output projection,
     # "kv_write" around the cache insert — disjoint, never nested
-    with jax.named_scope("attention"):
-        h = rms_norm(x, p["ln1"] if "ln1" in p else p["ln_mix"],
-                     cfg.norm_eps)
-        q = jnp.einsum("bsd,dk->bsk", h,
-                       p["wq"].astype(h.dtype)).reshape(B, S, Hp, hd)
-        k = jnp.einsum("bsd,dk->bsk", h,
-                       p["wk"].astype(h.dtype)).reshape(B, S, KVp, hd)
-        v = jnp.einsum("bsd,dk->bsk", h,
-                       p["wv"].astype(h.dtype)).reshape(B, S, KVp, hd)
-        if cfg.qk_norm:
-            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        q = shard(q, rules, "batch", None, "heads", None)
+    q, k, v = _project_qkv(x, p, cfg, rules, positions)
 
     def _flash(q, k, v):
         KVf = cfg.kv_flash
@@ -382,6 +369,36 @@ def _attention_mix(x, p, cfg: ModelConfig, rules: Rules, positions,
     else:
         with jax.named_scope("attention"):
             o = _flash(q, k, v)
+    return _project_out(o, x, p, cfg, rules), new_kv
+
+
+def _project_qkv(x, p, cfg: ModelConfig, rules: Rules, positions):
+    """Pre-norm q/k/v projections, q/k RMSNorm and RoPE: (B, S, Hp, hd)
+    queries and (B, S, KVp, hd) keys and values, under "attention"."""
+    B, S, _ = x.shape
+    hd, Hp, KVp = cfg.hd, cfg.h_padded, cfg.kv_param
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["ln1"] if "ln1" in p else p["ln_mix"],
+                     cfg.norm_eps)
+        q = jnp.einsum("bsd,dk->bsk", h,
+                       p["wq"].astype(h.dtype)).reshape(B, S, Hp, hd)
+        k = jnp.einsum("bsd,dk->bsk", h,
+                       p["wk"].astype(h.dtype)).reshape(B, S, KVp, hd)
+        v = jnp.einsum("bsd,dk->bsk", h,
+                       p["wv"].astype(h.dtype)).reshape(B, S, KVp, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        return shard(q, rules, "batch", None, "heads", None), k, v
+
+
+def _project_out(o, x, p, cfg: ModelConfig, rules: Rules):
+    """Attention output (B, S, Hp, hd) -> residual update (B, S, d), under
+    "attention"."""
+    B, S, d = x.shape
+    hd, Hp = cfg.hd, cfg.h_padded
     with jax.named_scope("attention"):
         o = shard(o, rules, "batch", None, "heads", None)
         # LBP row-parallel out-projection: contraction over model-sharded
@@ -389,14 +406,13 @@ def _attention_mix(x, p, cfg: ModelConfig, rules: Rules, positions,
         from . import lbp_linear
         from .tuning import reduce_pref_dtype
         if lbp_linear.applicable(rules):
-            y = lbp_linear.lbp_row_parallel(
+            return lbp_linear.lbp_row_parallel(
                 o.reshape(B, S, Hp * hd).astype(x.dtype),
                 p["wo"].astype(x.dtype), rules)
-            return y, new_kv
         y = jnp.einsum("bshk,hkD->bsD", o.astype(x.dtype),
                        p["wo"].reshape(Hp, hd, d).astype(x.dtype),
                        preferred_element_type=reduce_pref_dtype(x.dtype))
-        return shard(y.astype(x.dtype), rules, "batch", "seq", None), new_kv
+        return shard(y.astype(x.dtype), rules, "batch", "seq", None)
 
 
 def _ffn_mix(x, p, cfg: ModelConfig, rules: Rules, prefix=""):
@@ -653,11 +669,71 @@ def decode_step(params, cfg: ModelConfig, rules: Rules, token, pos, cache):
     x = shard(x, rules, "batch", None, None)
     x, cache = _stack_with_cache(x, params, cfg, rules, positions, cache,
                                  pos=pos)
+    return _decode_logits(x, params, cfg, rules), cache
+
+
+def _decode_logits(x, params, cfg: ModelConfig, rules: Rules):
+    """Final norm and the tied head: (B, 1, d) -> (B, 1, V) float32."""
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
                             params["embed"].astype(jnp.float32))
-        return shard(logits, rules, "batch", None, "vocab"), cache
+        return shard(logits, rules, "batch", None, "vocab")
+
+
+def paged_decode_step(params, cfg: ModelConfig, rules: Rules, token, pos,
+                      pool, table, write_table):
+    """One token per row against the physical page pool, in place.
+
+    token (S, 1) int32, pos (S,) int32; ``pool`` {"k", "v"} leaves
+    (L, n_pages + 1, page_size, KV, hd), the last page the trash page;
+    ``table`` the (S, pages_per_slot) READ page map, ``write_table`` the
+    WRITE map.  Returns (logits (S, 1, V), pool).  The layer scan carries
+    (x, pool): each layer writes its new K/V row through ``write_table``
+    and attends over the row's pages through ``table`` (dense causal
+    stacks only: the paged plane's families)."""
+    with jax.named_scope("embed"):
+        x = embed_tokens(token, params["embed"], rules)
+    x = shard(x, rules, "batch", None, None)
+
+    def body(carry, inp):
+        x, pool = carry
+        p, layer = inp
+        q, k, v = _project_qkv(x, p, cfg, rules, pos[:, None])
+        pool = _paged_kv_write(pool, layer, k[:, 0], v[:, 0], pos,
+                               write_table)
+        with jax.named_scope("attention"):
+            S, _, Hp, hd = q.shape
+            KVp = cfg.kv_param
+            o = paged_decode_attention(
+                q[:, 0].reshape(S, KVp, Hp // KVp, hd), pool["k"],
+                pool["v"], layer, table, pos)
+        x = x + _project_out(o.reshape(S, 1, Hp, hd), x, p, cfg, rules)
+        f, _ = _ffn_mix(x, p, cfg, rules)
+        return (x + f, pool), None
+
+    (x, pool), _ = jax.lax.scan(
+        body, (x, pool),
+        (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return _decode_logits(x, params, cfg, rules), pool
+
+
+def _paged_kv_write(pool, layer, k, v, pos, write_table):
+    """Write row b's new K/V (S, KV, hd) at ``(layer, write_table[b, pos_b
+    // page_size], pos_b % page_size)``.  Rows whose write lands on the
+    trash page (idle rows, write-protected shared pages) write zeros, so
+    racing duplicates agree and the trash page stays all-zero."""
+    with jax.named_scope("kv_write"):
+        n_phys, page_size = pool["k"].shape[1:3]
+        col = jnp.minimum(pos // page_size, write_table.shape[1] - 1)
+        page = jnp.take_along_axis(write_table, col[:, None], axis=1)[:, 0]
+        off = pos % page_size
+        live = (page != n_phys - 1)[:, None, None]
+
+        def put(leaf, row):
+            row = jnp.where(live, row, 0).astype(leaf.dtype)
+            return leaf.at[layer, page, off].set(row)
+        return {"k": put(pool["k"], k), "v": put(pool["v"], v)}
 
 
 def _stack_with_cache(x, params, cfg, rules, positions, cache, pos):

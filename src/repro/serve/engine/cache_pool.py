@@ -20,10 +20,10 @@ admission is gated on free **pages**, not free slots:
     can never be starved mid-flight (preemption-free reservation);
   * prefill claims only the pages the prompt needs; decode claims more
     lazily (*grow-on-decode*), structurally bounded by the reservation;
-  * unclaimed logical pages point at the trash page, so whole-view
-    scatters are always well-defined (the trash page absorbs garbage
-    rows that are never read back — decode attention masks positions
-    beyond each request's depth).
+  * unclaimed logical pages point at the trash page, so every write is
+    well-defined (the trash page absorbs writes from idle rows as zeros,
+    and reads of it are masked: decode attention masks positions beyond
+    each request's depth).
 
 **Prefix sharing (``share_prefixes=True``)** adds the production
 capacity lever: prompts that agree on their leading FULL pages map those
@@ -40,13 +40,14 @@ dispatches that already exist:
     *copied* — claimed privately and written by the request's own
     prefill scatter — which is the only place a request's token stream
     diverges from the shared region;
-  * every pool keeps TWO host page maps: ``table`` (the read map the
-    decode gather uses) and ``write_table`` (the write map the
-    scatters use), and a shared page's write entries are the trash
+  * every pool keeps TWO host page maps: ``table`` (the read map decode
+    attention uses) and ``write_table`` (the write map decode's row
+    writes and prefill's scatter use), and a shared page's write entries
+    are the trash
     page for every holder — once a page is sealed, no dispatch can
     write it, so "no request ever writes a page with refcount > 1"
-    holds structurally (property-tested) and the scatter never sees
-    duplicate non-trash indices;
+    holds structurally (property-tested) and no write sees duplicate
+    non-trash indices;
   * growth pages (decode writes) are always private, so grow-on-decode
     and the reservation argument are unchanged.
 
@@ -55,10 +56,11 @@ Both pools are pure id bookkeeping with conservation counters
 page is allocated once and freed once — when its refcount hits zero —
 no matter how many requests attached to it).  The tensor side lives in
 the helper functions: ``write_slot`` splices a prefilled row into the
-slot pool; ``gather_page_view`` / ``scatter_page_view`` translate
-between the physical page pool and the per-slot contiguous *view* the
-decode math runs on (one gather + one scatter inside the same jitted
-dispatch, so the step count stays identical to the slot plane).
+slot pool; ``scatter_page_view`` writes prefilled rows into their pages
+and ``gather_page_view`` is its inverse, the per-slot contiguous *view*
+(decode reads and writes the pool in place, see
+``models.transformer.paged_decode_step``; the view is the oracle its
+tests compare against).
 """
 
 from __future__ import annotations
@@ -521,9 +523,11 @@ def gather_page_view(pool_tree, table):
     appear in several rows, which is exactly how prefix sharing reuses
     one prompt's KV across requests.  Returns leaves of shape
     ``(L, n_slots, pages_per_slot * page_size, ...)`` — exactly the slot
-    plane's layout, so the unchanged decode math runs on the view and
+    plane's layout, so the slot plane's decode math runs on the view and
     positions beyond a request's depth (stale bytes in freshly claimed
-    pages) are masked by decode attention.
+    pages) are masked by decode attention.  Serving decode does not
+    build it (it reads pages in place); it is the oracle the in-place
+    path is tested against.
 
     Trash-backed logical pages are forced to exact ZEROS rather than the
     trash page's bytes: the trash page absorbs racing duplicate scatter

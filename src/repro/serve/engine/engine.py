@@ -197,9 +197,10 @@ class PagedTransformerModel(TransformerModel):
     every decode stretch are ONE jitted call — but the cache pytree is a
     physical page pool (``n_pages + 1`` pages of ``page_size`` token rows
     per layer; the extra page is the trash page) and every dispatch takes
-    the host-maintained page table as an argument.  Gather/scatter via
-    the table happens *inside* the jit (serve.step paged builders), so
-    the paged plane adds zero dispatches over the slot plane.
+    the host-maintained page tables as arguments.  Decode reads K/V
+    through the READ table and writes each new row through the WRITE
+    table *inside* the jit (serve.step paged builders), so the paged plane
+    adds zero dispatches over the slot plane.
 
     Restricted to purely-causal attention caches (dense/moe, no window):
     recurrent state mixes batch axes and ring-windowed caches wrap
@@ -625,11 +626,14 @@ class ServingEngine:
                                   lane="engine", k=k, rows=len(live)):
                 self.pool.prepare_decode(live, k)
             paged = isinstance(self.pool, PagedCachePool)
+            # kv_read: where decode attention reads K/V — the page pool
+            # in place ("pages") or the per-slot cache rows ("slots")
             dk_key = self.tracer.begin(
                 "decode", track=self.name, lane="engine", k=k,
                 rows=len(live),
                 depth_sum=sum(r.prompt_len + r.n_generated for r in live),
-                pages_used=self.pool.used_pages if paged else 0)
+                pages_used=self.pool.used_pages if paged else 0,
+                kv_read="pages" if paged else "slots")
             self.cache, rows, self._tok, self._pos = self.model.decode_multi(
                 self.cache, self._tok, self._pos, k)
             self._trace.append(rows)       # (k, n_slots)

@@ -8,11 +8,10 @@ contraction — see models/transformer.cache_specs).
 The continuous-batching engine (``serve.engine``) consumes these step
 builders through the jit caches below — one decode compilation per
 (config, rules) no matter how many requests are served.  The *paged*
-builders wrap the same decode math in a page-table gather/scatter
-(``serve.engine.cache_pool``): the physical page pool is reshaped into
-the per-slot contiguous view inside the SAME jitted call, so a paged
-decode step is still ONE dispatch and its logits are bit-compatible with
-the slot plane's (masked positions contribute exact zeros).
+builders run the same layers against the physical page pool
+(``serve.engine.cache_pool``) in place: attention reads each row's pages
+through the page table and each layer writes only its new K/V row, in the
+SAME jitted call, so a paged decode step is still ONE dispatch.
 ``greedy_generate`` is the reference oracle for both planes: under greedy
 decoding the engines must reproduce its outputs token-for-token
 (tests/test_serve_engine.py enforces this).
@@ -47,44 +46,39 @@ def make_decode_step(cfg: ModelConfig, rules: Rules):
 
 
 def make_paged_decode_step(cfg: ModelConfig, rules: Rules):
-    """One decode step against a paged pool: gather the per-slot view via
-    the page table, decode, scatter the view back — one fused dispatch.
-    ``pool`` leaves are (L, n_pages + 1, page_size, ...); ``table`` is the
+    """One decode step against a paged pool, in place: each layer writes
+    its new K/V row into the row's page and attends over the row's pages
+    straight from the pool — one dispatch, no per-slot view.  ``pool``
+    leaves are (L, n_pages + 1, page_size, ...); ``table`` is the
     (n_slots, pages_per_slot) int32 READ page map and ``write_table`` the
     WRITE map (identical unless prefix sharing masks shared pages to the
-    trash page — the copy-on-write discipline lives entirely in which
-    map each half of the dispatch uses)."""
-    from .engine.cache_pool import gather_page_view, scatter_page_view
-    base = make_decode_step(cfg, rules)
-
+    trash page — the copy-on-write discipline lives entirely in which map
+    the read and the write use)."""
     def step(params, token, pos, pool, table, write_table):
-        view = gather_page_view(pool, table)
-        next_token, logits, view = base(params, token, pos, view)
-        pool = scatter_page_view(pool, view, write_table)
+        logits, pool = T.paged_decode_step(params, cfg, rules, token, pos,
+                                           pool, table, write_table)
+        with jax.named_scope("lm_head"):
+            next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_token, logits, pool
     return step
 
 
 def make_paged_decode_scan(cfg: ModelConfig, rules: Rules, k: int):
     """``k`` fused decode steps on the paged plane in one dispatch.  The
-    view is gathered once (via the READ map), the scan carries it (the
-    page maps are fixed for the whole stretch — the engine claims every
-    page the k steps will write *before* dispatching), and the pages are
-    written back once via the WRITE map."""
-    from .engine.cache_pool import gather_page_view, scatter_page_view
-    base = make_decode_step(cfg, rules)
+    scan carries the pool (the page maps are fixed for the whole stretch —
+    the engine claims every page the k steps will write *before*
+    dispatching); each step writes one K/V row per layer and row."""
+    step = make_paged_decode_step(cfg, rules)
 
     def run(params, tok, pos, pool, table, write_table):
-        view = gather_page_view(pool, table)
-
         def body(carry, _):
-            tok, pos, view = carry
-            nxt, _, view = base(params, tok[:, None], pos, view)
-            return (nxt, pos + 1, view), nxt
+            tok, pos, pool = carry
+            nxt, _, pool = step(params, tok[:, None], pos, pool, table,
+                                write_table)
+            return (nxt, pos + 1, pool), nxt
 
-        (tok, pos, view), stack = jax.lax.scan(body, (tok, pos, view),
+        (tok, pos, pool), stack = jax.lax.scan(body, (tok, pos, pool),
                                                None, length=k)
-        pool = scatter_page_view(pool, view, write_table)
         return pool, stack, tok, pos
     return run
 

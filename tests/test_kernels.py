@@ -207,3 +207,44 @@ def test_flash_matches_xla_flash():
     xla = xla[:, :, :, 0, :].transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(pallas), np.asarray(xla),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("G", [4, 5])          # granite's and qwen3's GQA
+@pytest.mark.parametrize("page_size,npp", [(4, 10), (16, 20)])
+def test_paged_decode_attention_vs_ref(G, page_size, npp):
+    """Kernel == XLA reference over a fragmented pool: depths from 0 to
+    view_len - 1 (page boundaries and the last position included), two
+    rows sharing pages, idle rows (all trash, stale positions, one past
+    the view) first, in the middle and last, and a layer other than 0; a
+    40-position view is one block per row, a 320-position view two, with
+    the next block and the next live row's first in flight."""
+    from repro.kernels.paged_decode_attention_kernel import (
+        paged_decode_attention_pallas)
+    L, KV, hd, S = 3, 2, 128, 9
+    ps, n_pages = page_size, 4 * npp
+    rng = np.random.default_rng(G + ps)
+    pool = lambda k: rand((L, n_pages + 1, ps, KV, hd), jnp.bfloat16,
+                          k).at[:, n_pages].set(0)
+    k_pool, v_pool = pool(20 + G), pool(30 + G)
+    q = rand((S, KV, G, hd), jnp.bfloat16, 40 + G)
+    pos = np.concatenate([[7, 0, ps, 3, ps * npp - 1, ps - 1],
+                          rng.integers(0, ps * npp, 2), [10 ** 4]])
+    idle = (0, 3, S - 1)
+    table = np.full((S, npp), n_pages, np.int32)
+    pages = iter(rng.permutation(n_pages).tolist() * 2)
+    for row in set(range(S)) - set(idle):
+        for col in range(int(pos[row]) // ps + 1):
+            table[row, col] = next(pages)
+    table[6, :2] = table[4, :2]                # shared pages
+    args = (q, k_pool, v_pool, jnp.int32(L - 1), jnp.asarray(table),
+            jnp.asarray(pos, jnp.int32))
+    out = paged_decode_attention_pallas(*args, interpret=True)
+    expect = ref.paged_decode_attention_ref(*args)
+    assert out.shape == expect.shape == (S, KV, G, hd)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect, np.float32),
+                               rtol=2e-2, atol=2e-2)

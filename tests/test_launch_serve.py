@@ -142,3 +142,31 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     for line in r.stdout.splitlines():
         with pytest.raises(json.JSONDecodeError):
             json.loads(line)
+
+
+def test_compile_cache_key_of_a_kernel_ignores_its_caller():
+    """Once the cache is in use, a program holding the paged decode kernel
+    has one persistent-cache key whatever script and line traced it."""
+    code = (
+        "import jax, jax.numpy as jnp, hashlib\n"
+        "from jax._src import cache_key\n"
+        "from repro.launch.compile_cache import use_compile_cache\n"
+        "from repro.kernels.paged_decode_attention_kernel import "
+        "paged_decode_attention_pallas as f\n"
+        "use_compile_cache()\n"
+        "pool = jax.ShapeDtypeStruct((2, 5, 4, 2, 128), jnp.bfloat16)\n"
+        "i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)\n"
+        "args = (jax.ShapeDtypeStruct((3, 2, 4, 128), jnp.bfloat16), pool,"
+        " pool, i32(), i32(3, 2), i32(3))\n"
+        "{pad}m = jax.jit(lambda *a: f(*a, interpret=False)).trace(*args)"
+        ".lower(lowering_platforms=('tpu',)).compiler_ir()\n"
+        "print(hashlib.sha256(cache_key._canonicalize_ir("
+        "m, cache_key.IgnoreCallbacks.NO)).hexdigest())\n")
+    keys = []
+    for pad in ("", "pass\n\n\n"):        # the tracing line moves
+        r = subprocess.run([sys.executable, "-c", code.format(pad=pad)],
+                           capture_output=True, text=True, timeout=120,
+                           env=_cpu_env())
+        assert r.returncode == 0, r.stderr
+        keys.append(r.stdout.split()[-1])
+    assert keys[0] == keys[1]

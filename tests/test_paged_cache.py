@@ -688,3 +688,129 @@ def test_prefix_sharing_fleet_kill_requeue_oracle(small_lm):
     # the survivor actually shared (requeued + native traffic both hit
     # its index); the dead pool is abandoned whole, never drained
     assert ctrl.replicas["r1"].engine.pool.n_shared_attached > 0
+
+
+# ---------------------------------------------------------------------------
+# in-place paged decode against the view composition it replaced
+# ---------------------------------------------------------------------------
+
+PAGE, PPS, N_PAGES = 4, 5, 24          # view of 20 positions, trash page 24
+
+
+def _old_paged_decode(cfg, k):
+    """The view composition: gather the per-slot view through the READ
+    map, run the slot plane's decode on it, scatter it back through the
+    WRITE map (kept here as the oracle of the in-place path)."""
+    from repro.serve.engine import gather_page_view, scatter_page_view
+    from repro.serve.step import make_decode_step
+    base = make_decode_step(cfg, RULES)
+
+    def run(params, tok, pos, pool, table, write_table):
+        view = gather_page_view(pool, table)
+        toks, logits = [], []
+        for _ in range(k):
+            tok, lg, view = base(params, tok[:, None], pos, view)
+            toks.append(tok)
+            logits.append(lg[:, -1])
+            pos = pos + 1
+        pool = scatter_page_view(pool, view, write_table)
+        return pool, jnp.stack(toks), jnp.stack(logits)
+    return jax.jit(run)
+
+
+def _new_paged_decode(cfg, k):
+    from repro.serve.step import make_paged_decode_step
+    step = make_paged_decode_step(cfg, RULES)
+
+    def run(params, tok, pos, pool, table, write_table):
+        toks, logits = [], []
+        for _ in range(k):
+            tok, lg, pool = step(params, tok[:, None], pos, pool, table,
+                                 write_table)
+            toks.append(tok)
+            logits.append(lg[:, -1])
+            pos = pos + 1
+        return pool, jnp.stack(toks), jnp.stack(logits)
+    return jax.jit(run)
+
+
+def _paged_state(cfg, k, seed=0):
+    """A fragmented pool with random K/V on every real page, the trash
+    page zero, and six rows: depth 0, a page boundary, the view's last
+    position (k = 1; the last k positions for k > 1), two rows sharing
+    their first two pages (write-protected in the WRITE map) and an idle
+    row (all trash, a stale position)."""
+    rng = np.random.default_rng(seed)
+    pool = T.init_cache(cfg, N_PAGES + 1, PAGE)
+    pool = {n: jnp.asarray(rng.standard_normal(leaf.shape), leaf.dtype)
+            .at[:, N_PAGES].set(0) for n, leaf in pool.items()}
+    view = PAGE * PPS
+    pos = np.array([0, PAGE, view - k, 2 * PAGE + 1, 3 * PAGE - 1, 3],
+                   np.int32)
+    pages = iter(rng.permutation(N_PAGES).tolist())
+    table = np.full((6, PPS), N_PAGES, np.int32)
+    shared = [next(pages), next(pages)]
+    for row in range(5):
+        last = (int(pos[row]) + k - 1) // PAGE
+        for col in range(last + 1):
+            table[row, col] = (shared[col] if row in (3, 4) and col < 2
+                               else next(pages))
+    write_table = table.copy()
+    write_table[3:5, :2] = N_PAGES
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, 6), jnp.int32)
+    return (tok, jnp.asarray(pos), pool, jnp.asarray(table),
+            jnp.asarray(write_table))
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_3b", "qwen3_14b"])
+@pytest.mark.parametrize("k", [1, 4])
+def test_paged_decode_in_place_matches_view_composition(arch, k):
+    """The in-place paged decode (one step and the fused k-step stretch)
+    against gather -> slot decode -> scatter: the same next tokens on
+    every live row, logits within bf16 rounding, the same bytes on every
+    real page, and a trash page that stays all zeros."""
+    cfg = get_reduced(arch)
+    params = T.init_params(cfg, jax.random.PRNGKey(1))
+    tok, pos, pool, table, write_table = _paged_state(cfg, k)
+    old_pool, old_tok, old_lg = _old_paged_decode(cfg, k)(
+        params, tok, pos, pool, table, write_table)
+    new_pool, new_tok, new_lg = _new_paged_decode(cfg, k)(
+        params, tok, pos, pool, table, write_table)
+    live = slice(0, 5)              # row 5 is idle: its output is unused
+    np.testing.assert_array_equal(np.asarray(new_tok)[:, live],
+                                  np.asarray(old_tok)[:, live])
+    np.testing.assert_allclose(np.asarray(new_lg)[:, live],
+                               np.asarray(old_lg)[:, live],
+                               rtol=2e-2, atol=2e-2)
+    for name in ("k", "v"):
+        new, old = np.asarray(new_pool[name]), np.asarray(old_pool[name])
+        np.testing.assert_array_equal(new[:, :N_PAGES], old[:, :N_PAGES])
+        assert not new[:, N_PAGES].any()
+        # the write landed: every live row's new positions differ from
+        # the random bytes they held, shared pages did not move
+        before = np.asarray(pool[name])
+        assert (new[:, table[0, 0], 0] != before[:, table[0, 0], 0]).any()
+        shared = np.asarray(table)[3, :2]
+        np.testing.assert_array_equal(new[:, shared], before[:, shared])
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_paged_decode_programs_hold_no_view(small_lm, k):
+    """Structural guard: the compiled paged decode programs (the adapter's
+    one-step program and the fused scan) hold no array of the per-slot
+    view's shape (L, n_slots, view_len, KV, hd) and no operation in a
+    page_gather or page_scatter scope."""
+    from repro.serve.step import make_paged_decode_scan
+    cfg, params = small_lm
+    tok, pos, pool, table, write_table = _paged_state(cfg, k)
+    if k == 1:
+        fn = PagedTransformerModel(params, cfg, RULES)._paged_decode1
+    else:
+        fn = jax.jit(make_paged_decode_scan(cfg, RULES, k))
+    text = fn.lower(params, tok, pos, pool, table,
+                    write_table).compile().as_text()
+    L, _, _, KV, hd = pool["k"].shape
+    view = f"[{L},6,{PAGE * PPS},{KV},{hd}]"
+    assert view not in text
+    assert "page_gather" not in text and "page_scatter" not in text
+    assert "kv_write" in text and "attention" in text

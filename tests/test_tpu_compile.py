@@ -104,17 +104,31 @@ def test_param_init_emits_bf16(llama):
     assert n_bytes < 7 * 2**30
 
 
+def _reads_pages_in_place(compiled, llama):
+    """The paged decode kernel is in the program, and no buffer has the
+    per-slot view's shape (L, n_slots, view_len, KV, hd)."""
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "paged_decode_attention" in hlo
+    L, _, _, KV, hd = llama["pool"]["k"].shape
+    assert f"[{L},{N_SLOTS},{CACHE_LEN},{KV},{hd}]" not in hlo
+
+
 def test_paged_decode_step(llama):
     m = llama["model"]
-    _fits(m._paged_decode1.lower(
+    compiled = m._paged_decode1.lower(
         llama["params"], llama["vec"], llama["vec"], llama["pool"],
-        llama["table"], llama["table"]).compile())
+        llama["table"], llama["table"]).compile()
+    _fits(compiled)
+    _reads_pages_in_place(compiled, llama)
 
 
 def test_paged_decode_multi_scan(llama):
     fn = jax.jit(llama["model"]._paged_scan_builder(4))
-    _fits(fn.lower(llama["params"], llama["vec"], llama["vec"],
-                   llama["pool"], llama["table"], llama["table"]).compile())
+    compiled = fn.lower(llama["params"], llama["vec"], llama["vec"],
+                        llama["pool"], llama["table"],
+                        llama["table"]).compile()
+    _fits(compiled)
+    _reads_pages_in_place(compiled, llama)
 
 
 def test_paged_group_prefill(llama, one_chip):
@@ -157,4 +171,20 @@ def test_pallas_flash_attention_compiles(one_chip):
                              sharding=one_chip)
     hlo = ops.flash_attention.lower(q, q, q, interpret=False
                                     ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("G", [4, 5])          # granite's and qwen3's GQA
+def test_pallas_paged_decode_attention_compiles(one_chip, G):
+    """The paged decode kernel alone at a cell's widths: 8 KV heads of
+    128, 16-token pages, 16 rows of 112 pages, a 10-layer pool."""
+    from repro.kernels.paged_decode_attention_kernel import (
+        paged_decode_attention_pallas)
+    pool = jax.ShapeDtypeStruct((10, 1793, 16, 8, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    q = jax.ShapeDtypeStruct((16, 8, G, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    hlo = jax.jit(paged_decode_attention_pallas).lower(
+        q, pool, pool, _i32((), one_chip), _i32((16, 112), one_chip),
+        _i32((16,), one_chip)).compile().as_text()
     assert "tpu_custom_call" in hlo
