@@ -67,7 +67,8 @@ def main(argv=None) -> int:
 
     import jax
     import numpy as np
-    from bench import cells, correctness, harness, model
+    from bench import cells, correctness, family, harness
+    from bench.common import seed_key
     from repro.fleet.replica import build_engine
 
     cell = cells.load_cell(args.workload, ROOT)
@@ -137,7 +138,8 @@ def main(argv=None) -> int:
     for seed in args.seeds or []:
         t = time.perf_counter()
         harness.free(adapter.params)
-        adapter.params = model.init_weights(cfg)(model.seed_key(seed))
+        adapter.params = family.load(cell.spec).init_weights(cfg)(
+            seed_key(seed))
         loop, e2e = window(seed, base, args.seconds)
         harness.free(adapter.params, loop.engine.cache)
         loop.engine = None
@@ -145,7 +147,8 @@ def main(argv=None) -> int:
         by_idx = {r.idx: (r.prompt, r.tokens) for r in recs}
         chosen = correctness.sample(by_idx, seed, positions, rows)
         t3 = time.perf_counter()
-        g = correctness.gaps(cfg, seed, [by_idx[i][0] for i in chosen],
+        g = correctness.gaps(cell.spec, cfg, seed,
+                             [by_idx[i][0] for i in chosen],
                              [by_idx[i][1] for i in chosen], positions,
                              rows, controls)
         judged = {}

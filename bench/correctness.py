@@ -2,17 +2,18 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the delivered requests, drawn from the seed and holding the request with
-the most served tokens, runs through the plain reference
-(``bench.reference``): each prompt followed by its served tokens, in one
-packed forward pass.  At each served token the gap is the reference's best
-logit minus its logit of the served token.  Two numbers are compared
-(``numbers``): the widest gap, and the mean gap over every checked token,
-which grows with the square of the logits' error and so also catches a
-lower precision in the weights alone.  Each limit sits between what sound
-runs of the program read and what the controls read (the reference one
-precision step lower: ``reference``'s ``quant``), as ``PERF.md`` records.
-A delivered request of the wrong length or with an id outside the
-vocabulary is wrong whatever its logits.
+the most served tokens, runs through the plain reference of the
+configuration's family (``bench.family``): each prompt followed by its
+served tokens, in one packed forward pass.  At each served token the gap
+is the reference's best logit minus its logit of the served token.  Two
+numbers are compared (``numbers``): the widest gap, and the mean gap over
+every checked token, which grows with the square of the logits' error and
+so also catches a lower precision in the weights alone.  Each limit sits
+between what sound runs of the program read and what the controls read
+(the reference one precision step lower: ``quant``, see
+``bench.common``), as ``PERF.md`` records.  A delivered request of the
+wrong length or with an id outside the vocabulary is wrong whatever its
+logits.
 """
 
 from __future__ import annotations
@@ -21,16 +22,16 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import reference
+from . import family
+from .common import Q_CHUNK, served_rows
 
 
 def budget(cell, scale: int = 1) -> Tuple[int, int]:
     """(packed positions, logit rows) one reference pass holds: two of the
     longest requests, and four of the longest outputs (``scale`` times
     that, for readings of other budgets)."""
-    chunk = reference.Q_CHUNK
     span = cell.max_prompt + cell.max_output
-    return (-(-2 * scale * span // chunk) * chunk,
+    return (-(-2 * scale * span // Q_CHUNK) * Q_CHUNK,
             4 * scale * cell.max_output)
 
 
@@ -55,25 +56,27 @@ def sample(delivered: Dict[int, Tuple[np.ndarray, np.ndarray]], seed: int,
     return chosen
 
 
-def gaps(cfg, seed: int, prompts: Sequence[np.ndarray],
+def gaps(spec: dict, cfg, seed: int, prompts: Sequence[np.ndarray],
          served: Sequence[np.ndarray], positions: int, rows: int,
          quants: Sequence[Optional[str]] = ()) -> Dict[str, np.ndarray]:
     """Per checked token, the gap under the float32 reference of the served
     token (``"served"``) and, for each ``quant``, of the token that the
-    lower precision would put first at the same position."""
+    lower precision would put first at the same position.  ``spec`` is
+    the configuration file, whose family gives the reference."""
     import jax.numpy as jnp
-    seqs, where = reference.served_rows(prompts, served)
+    logits = family.load(spec).reference_logits
+    seqs, where = served_rows(prompts, served)
     idx = np.concatenate(where)
     tokens = np.concatenate(served).astype(np.int32)
     n = len(idx)
     pad = np.zeros(rows, np.int32)
     pad[:n] = idx
-    ref = reference.logits(cfg, seed, seqs, pad, positions)[:n]
+    ref = logits(cfg, seed, seqs, pad, positions)[:n]
     best = ref.max(axis=-1)
     pick = lambda toks: ref[jnp.arange(n), jnp.asarray(toks)]
     out = {"served": np.asarray(best - pick(tokens), np.float64)}
     for q in quants:
-        low = reference.logits(cfg, seed, seqs, pad, positions, quant=q)[:n]
+        low = logits(cfg, seed, seqs, pad, positions, quant=q)[:n]
         out[q] = np.asarray(best - pick(low.argmax(axis=-1)), np.float64)
     return out
 

@@ -36,8 +36,9 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import correctness, model, traffic
+from . import correctness, family, traffic
 from . import trace as tr
+from .common import seed_key
 
 DRAIN_S = 60.0
 TRACE_S = 20.0     # a traced run profiles the window's last TRACE_S seconds
@@ -76,7 +77,9 @@ class CompileCount:
 
 @dataclasses.dataclass
 class Calls:
-    """What the harness saw the engine's layers do (``perf_counter`` s)."""
+    """What the harness saw the engine's layers do (``perf_counter`` s).
+    A decode record is (time, k, each live row's depth at the call's first
+    step, page occupancy); a prefill record is (time, prompt lengths)."""
     decode: List[tuple] = dataclasses.field(default_factory=list)
     prefill: List[tuple] = dataclasses.field(default_factory=list)
 
@@ -132,8 +135,9 @@ def build(cell, seed: int):
     from repro.serve.engine import PagedTransformerModel
     from repro.sharding.rules import Rules
 
-    cfg = model.model_config(cell.spec)
-    params = model.init_weights(cfg)(model.seed_key(seed))
+    fam = family.load(cell.spec)
+    cfg = fam.model_config(cell.spec)
+    params = fam.init_weights(cfg)(seed_key(seed))
     jax.block_until_ready(params)
     adapter = PagedTransformerModel(params, cfg, Rules.null())
     engine = build_engine(adapter, cell.engine_config())
@@ -382,7 +386,8 @@ def check(cell, cfg, seed: int, delivered: List[Record], undelivered: int,
     by_idx = {r.idx: (r.prompt, r.tokens) for r in delivered
               if r.tokens.shape == (r.max_new,)}
     chosen = correctness.sample(by_idx, seed, positions, rows)
-    gap = correctness.gaps(cfg, seed, [by_idx[i][0] for i in chosen],
+    gap = correctness.gaps(cell.spec, cfg, seed,
+                           [by_idx[i][0] for i in chosen],
                            [by_idx[i][1] for i in chosen], positions,
                            rows)["served"] if chosen else np.zeros(0)
     values = dict(correctness.numbers(gap),

@@ -37,20 +37,22 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import cells, model
+    from bench import cells, family
     from repro.models import transformer as T
     from repro.serve.engine import PagedTransformerModel
     from repro.serve.step import make_paged_decode_scan
     from repro.sharding.rules import Rules
 
     jax.config.update("jax_enable_compilation_cache", False)
-    spec = model.load_config(args.config)
+    spec = json.loads((cells.REPO / "bench/configs" / f"{args.config}.json")
+                      .read_text())
     traffic = json.loads((cells.TRAFFIC_DIR / f"{args.traffic}.json")
                          .read_text())
     cell = cells.Cell(f"{args.config}.{args.traffic}", 1, spec, traffic,
                       [], [])
     ec = cell.engine_config()
-    cfg = model.model_config(spec)
+    fam = family.load(spec)
+    cfg = fam.model_config(spec)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -63,7 +65,7 @@ def main(argv=None) -> int:
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
-    params = placed(jax.eval_shape(model.init_weights(cfg),
+    params = placed(jax.eval_shape(fam.init_weights(cfg),
                                    jax.ShapeDtypeStruct((2,), jnp.uint32)))
     n_pages, pps = ec.pool_pages, ec.pages_per_slot
     pool = placed(jax.eval_shape(

@@ -202,14 +202,16 @@ def decode_hlo_texts(cell, cfg, ks: Iterable[int]) -> Dict[str, List[str]]:
     import jax
     import jax.numpy as jnp
 
-    from bench import model
+    from bench import family
+    from bench.common import seed_key
     from repro.models import transformer as T
     from repro.serve.engine import PagedTransformerModel
     from repro.serve.step import make_paged_decode_scan
     from repro.sharding.rules import Rules
 
     ec = cell.engine_config()
-    params = jax.eval_shape(model.init_weights(cfg), model.seed_key(0))
+    params = jax.eval_shape(family.load(cell.spec).init_weights(cfg),
+                            seed_key(0))
     pool = jax.eval_shape(
         lambda: T.init_cache(cfg, ec.pool_pages + 1, ec.page_size))
     vec = jax.ShapeDtypeStruct((ec.n_slots,), jnp.int32)

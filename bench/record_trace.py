@@ -32,7 +32,8 @@ def serve_small():
     run; returns a function that serves eight requests, then sleeps."""
     import numpy as np
 
-    from bench import harness, model
+    from bench import family, harness
+    from bench.common import seed_key
     from repro.fleet.replica import build_engine
     from repro.models.config import ModelConfig
     from repro.serve.engine import EngineConfig, PagedTransformerModel
@@ -41,7 +42,8 @@ def serve_small():
     cfg = ModelConfig(name="small", family="dense", n_layers=2, d_model=256,
                       n_heads=4, n_kv_heads=2, d_ff=512, vocab_size=512,
                       head_dim=64, tp=1)
-    params = model.init_weights(cfg)(model.seed_key(1))
+    params = family.load({"family": cfg.family}).init_weights(cfg)(
+        seed_key(1))
     adapter = PagedTransformerModel(params, cfg, Rules.null())
     engine = build_engine(adapter, EngineConfig(
         n_slots=4, max_prompt_len=64, max_new_cap=16, cache_len=80,

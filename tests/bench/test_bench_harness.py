@@ -19,7 +19,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench import cells, correctness, harness, model
+from bench import cells, common, correctness, family, harness
 from smallcell import small_spec, small_traffic
 
 LIMITS = {"max_logit_gap": 0.03, "mean_logit_gap": 0.001}
@@ -91,19 +91,21 @@ def test_control_one_precision_lower_fails_the_limit():
     puts first tokens whose float32 gap passes the limit, where the
     program's served tokens stay under it."""
     cell = _cell("open")
-    cfg = model.model_config(cell.spec)
+    dense = family.load(cell.spec)
+    cfg = dense.model_config(cell.spec)
     seed = 1
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (16, 48, 48, 16)]
     from repro.serve.engine import serve_requests
     from repro.sharding.rules import Rules
-    params = model.init_weights(cfg)(model.seed_key(seed))
+    params = dense.init_weights(cfg)(common.seed_key(seed))
     rep = serve_requests(params, cfg, Rules.null(),
                          [(p, 24, 0.0) for p in prompts], n_slots=4,
                          page_size=16)
     served = [rep.completed[i] for i in range(len(prompts))]
-    g = correctness.gaps(cfg, seed, prompts, served, 256, 128, ["fp8"])
+    g = correctness.gaps(cell.spec, cfg, seed, prompts, served, 256, 128,
+                         ["fp8"])
     program, control = (correctness.numbers(g[k]) for k in ("served", "fp8"))
     assert correctness.judge(program, LIMITS)[1]
     assert not correctness.judge(control, LIMITS)[1]

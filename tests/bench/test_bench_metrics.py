@@ -6,9 +6,11 @@ import pathlib
 
 import pytest
 
-from bench import costs, harness, model, peaks
+from bench import family, harness, peaks
 from bench import trace as tr
 from smallcell import small_spec, small_traffic
+
+dense = family.load(small_spec())
 
 REL = 1e-3   # see test_bench_trace: the export keeps picoseconds
 DATA = pathlib.Path(__file__).resolve().parents[2] / "bench/testdata/trace"
@@ -19,7 +21,7 @@ def ctx():
     from bench import cells
     t = tr.load(str(DATA / "trace.xplane.pb"))
     cell = cells.Cell("small.open", 1, small_spec(), small_traffic(), [], [])
-    cfg = model.model_config(cell.spec)
+    cfg = dense.model_config(cell.spec)
     calls = harness.Calls(
         decode=[(0.0, 1, [20, 40, 60], 0.25), (0.1, 4, [21, 41], 0.5)],
         prefill=[(0.0, [16, 48]), (0.2, [16])])
@@ -54,14 +56,18 @@ def test_roofline_and_mfu_from_costs(ctx):
     cfg, pk = ctx.cfg, ctx.peaks
     p = _expected()["programs"]
     dec_s = p["jit_paged_decode1"]["seconds"] + p["jit_run"]["seconds"]
-    need = costs.decode_step_bytes(cfg, [20, 40, 60]) + sum(
-        costs.decode_step_bytes(cfg, [21 + i, 41 + i]) for i in range(4))
+    # one step of a call: (time, k=1, depths, occupancy); the k=4 call's
+    # steps attend one position further each
+    step = lambda depths: (0.0, 1, depths, 0.0)
+    need = dense.decode_step_bytes(cfg, step([20, 40, 60])) + sum(
+        dense.decode_step_bytes(cfg, step([21 + i, 41 + i]))
+        for i in range(4))
     assert harness.read_metric("decode_hbm_roofline", ctx) == pytest.approx(
         100 * need / pk["hbm_bytes_per_s"] / dec_s, rel=REL)
-    flops = (costs.decode_flops(cfg, [20, 40, 60])
-             + sum(costs.decode_flops(cfg, [21 + i, 41 + i])
+    flops = (dense.decode_flops(cfg, step([20, 40, 60]))
+             + sum(dense.decode_flops(cfg, step([21 + i, 41 + i]))
                    for i in range(4))
-             + 2 * costs.prefill_flops(cfg, 16) + costs.prefill_flops(cfg, 48))
+             + 2 * dense.prefill_flops(cfg, 16) + dense.prefill_flops(cfg, 48))
     assert harness.read_metric("mfu", ctx) == pytest.approx(
         100 * flops / (ctx.served_s * pk["bf16_flops"]))
 
@@ -73,14 +79,14 @@ def test_idle_share_is_what_busy_leaves(ctx):
 
 
 def test_costs_by_hand():
-    cfg = model.model_config(small_spec())
+    cfg = dense.model_config(small_spec())
     d, hd, ff, V, L = 128, 32, 256, 512, 2
     per_layer = d * 4 * hd * 2 + d * 2 * hd * 2 + 3 * d * ff
-    assert costs.layer_params(cfg) == per_layer
-    assert costs.weight_bytes(cfg) == 2 * (L * (per_layer + 2 * d)
+    assert dense.layer_params(cfg) == per_layer
+    assert dense.weight_bytes(cfg) == 2 * (L * (per_layer + 2 * d)
                                            + V * d + d)
-    assert costs.kv_bytes_per_position(cfg) == 2 * 2 * L * 2 * hd
-    assert costs.prefill_flops(cfg, 3) == (
+    assert dense.kv_bytes_per_position(cfg) == 2 * 2 * L * 2 * hd
+    assert dense.prefill_flops(cfg, 3) == (
         2 * L * per_layer * 3 + 4 * 4 * hd * 6 * L + 2 * V * d)
 
 

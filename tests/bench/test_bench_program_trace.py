@@ -19,9 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import cells, harness
+from bench import cells, family, harness
 from bench import program_trace as pt
 from bench import trace as tr
+from bench.common import seed_key
 from smallcell import small_spec, small_traffic
 
 DATA = pathlib.Path(__file__).resolve().parents[2] / "bench/testdata"
@@ -110,15 +111,15 @@ def test_compiled_programs_carry_every_scope(small_run):
     texts = pt.decode_hlo_texts(cell, cfg, ks)
     assert set(texts) == {"jit_paged_decode1", "jit_run"}
     decode = set(pt.scope_map(texts).values())
-    assert {"page_gather", "page_scatter", "kv_write", "attention", "mlp",
-            "lm_head", "embed", pt.UNSCOPED} <= decode
+    assert {"kv_write", "attention", "mlp", "lm_head", "embed",
+            pt.UNSCOPED} <= decode
 
     from repro.models import transformer as T
     from repro.serve.engine import PagedTransformerModel
     from repro.sharding.rules import Rules
     ec = cell.engine_config()
-    params = jax.eval_shape(lambda: harness.model.init_weights(cfg)(
-        harness.model.seed_key(0)))
+    params = jax.eval_shape(lambda: family.load(cell.spec).init_weights(cfg)(
+        seed_key(0)))
     adapter = PagedTransformerModel(params, cfg, Rules.null())
     pool = jax.eval_shape(
         lambda: T.init_cache(cfg, ec.pool_pages + 1, ec.page_size))
