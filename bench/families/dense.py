@@ -43,6 +43,9 @@ IDS = {"embed": 0, "final_norm": 1, "ln1": 2, "ln2": 3, "wq": 4, "wk": 5,
        "wv": 6, "wo": 7, "q_norm": 8, "k_norm": 9, "w_gate": 10,
        "w_up": 11, "w_down": 12}
 NORMS = ("final_norm", "ln1", "ln2", "q_norm", "k_norm")
+# the program's ``jax.named_scope``s in this family's programs
+SCOPES = ("embed", "attention", "kv_write", "mlp", "lm_head", "page_gather",
+          "page_scatter")
 
 
 def model_config(spec: dict):
@@ -199,8 +202,7 @@ def kv_bytes_per_position(cfg, itemsize: int = 2) -> int:
 def _step_depths(call):
     """Each step's live depths in a decode call: the rows' depths at its
     first step, one further at each later one."""
-    k, depths = call[1], call[2]
-    return [[d + i for d in depths] for i in range(k)]
+    return [[d + i for d in call.depths] for i in range(call.k)]
 
 
 def decode_step_bytes(cfg, call) -> int:

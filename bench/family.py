@@ -13,7 +13,11 @@ A family is one file, ``bench/families/<family>.py``, that provides:
 - ``weight_bytes(cfg)``, ``decode_step_bytes(cfg, call)``,
   ``decode_flops(cfg, call)``, ``prefill_flops(cfg, length)``: operations
   and bytes from shapes, where ``call`` is one record of the harness's
-  ``Calls.decode`` (its ``k`` steps and its live rows' depths).
+  ``Calls.decode`` (``harness.Decode``: its ``k`` steps, its live rows'
+  depths and, in a traced run, the numeric arguments of the program's
+  spans around the call in ``program``);
+- ``SCOPES``: the ``jax.named_scope`` names that the family's programs
+  carry, by which ``bench.program_trace`` splits their device time.
 """
 
 from __future__ import annotations

@@ -32,17 +32,20 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Dict, List, Optional
+from types import MappingProxyType
+from typing import Dict, List, Mapping, NamedTuple, Optional
 
 import numpy as np
 
 from . import correctness, family, traffic
+from . import program_trace as pt
 from . import trace as tr
 from .common import seed_key
 
 DRAIN_S = 60.0
 TRACE_S = 20.0     # a traced run profiles the window's last TRACE_S seconds
 METRICS_DIR = pathlib.Path(__file__).resolve().parent / "metrics"
+DECODE_CALL = "decode_multi"   # the adapter's decode entry the harness wraps
 
 
 @dataclasses.dataclass
@@ -75,12 +78,25 @@ class CompileCount:
             self.seconds += duration
 
 
+class Decode(NamedTuple):
+    """One call of the adapter's ``decode_multi``, as the harness saw it:
+    ``time`` (``perf_counter`` s, before the call), ``k`` fused steps, each
+    live row's depth at the first step, the page pool's occupancy, and in
+    a traced run ``program``: the numeric arguments of the program's own
+    spans around the call, summed by name (``with_program``)."""
+    time: float
+    k: int
+    depths: List[int]
+    occupancy: float
+    program: Mapping[str, float] = MappingProxyType({})
+
+
 @dataclasses.dataclass
 class Calls:
-    """What the harness saw the engine's layers do (``perf_counter`` s).
-    A decode record is (time, k, each live row's depth at the call's first
-    step, page occupancy); a prefill record is (time, prompt lengths)."""
-    decode: List[tuple] = dataclasses.field(default_factory=list)
+    """What the harness saw the engine's layers do: a ``Decode`` record
+    per decode call, and a prefill record (time, prompt lengths) per
+    prefill call."""
+    decode: List[Decode] = dataclasses.field(default_factory=list)
     prefill: List[tuple] = dataclasses.field(default_factory=list)
 
     def window(self, t0: float, t1: float) -> "Calls":
@@ -113,8 +129,8 @@ def instrument(engine, adapter, calls: Calls, traced: bool):
     def note_decode(cache, tok, pos, k):
         depths = [r.prompt_len + r.n_generated
                   for r in sched.active.values() if not r.done]
-        calls.decode.append((time.perf_counter(), int(k), depths,
-                             pool.occupancy))
+        calls.decode.append(Decode(time.perf_counter(), int(k), depths,
+                                   pool.occupancy))
 
     def note_prefill(cache, prompts, slots, tok, pos):
         calls.prefill.append((time.perf_counter(),
@@ -124,8 +140,26 @@ def instrument(engine, adapter, calls: Calls, traced: bool):
     wrap(sched, "plan", "scheduler.plan")
     wrap(pool, "prepare_decode", "pool.prepare_decode")
     wrap(adapter, "prefill", "prefill", note_prefill)
-    wrap(adapter, "decode_multi", "decode_multi", note_decode)
+    wrap(adapter, DECODE_CALL, DECODE_CALL, note_decode)
     return span
+
+
+def with_program(calls: Calls, trace: tr.Trace) -> Calls:
+    """``calls`` with each decode record's ``program``: the arguments of
+    the program's spans around that call (``program_trace.call_args``).
+    The n-th record's call is the n-th of the harness's own spans around
+    the adapter's decode in the trace's window; where the two counts
+    differ, the records are returned as they are."""
+    lo, hi = trace.window
+    wraps = [(a, b) for a, b, name in trace.host
+             if name == tr.PREFIX + DECODE_CALL and lo <= a < hi]
+    if len(wraps) != len(calls.decode):
+        print(f"trace: {len(wraps)} decode calls traced, {len(calls.decode)}"
+              f" recorded: no program arguments", file=sys.stderr)
+        return calls
+    args = pt.call_args(trace.spans, wraps)
+    return Calls([c._replace(program=a) for c, a in zip(calls.decode, args)],
+                 calls.prefill)
 
 
 def build(cell, seed: int):
@@ -303,15 +337,21 @@ def end_to_end(name: str, delivered: List[Record], tokens: int,
 
 
 class Context:
-    """What a per-layer metric's reader may read."""
+    """What a per-layer metric's reader may read: besides the cell, the
+    configuration, the peaks, the window's calls and the trace, the
+    program's spans that overlap the window (``spans``) and the decode
+    programs' scope map (``scope_map``, ``program_trace.scope_map``)."""
 
     def __init__(self, cell, cfg, peaks, calls: Calls, trace, clip_ns,
-                 served_ns, served_s):
+                 served_ns, served_s, scope_map=None):
         self.cell, self.cfg, self.peaks = cell, cfg, peaks
         self.n_slots = cell.n_slots
         self.calls, self.trace = calls, trace
         self.window_ns, self.served_ns = clip_ns, served_ns
         self.served_s = served_s
+        lo, hi = min(a for a, _ in clip_ns), max(b for _, b in clip_ns)
+        self.spans = [s for s in trace.spans if s[1] > lo and s[0] < hi]
+        self.scope_map = scope_map
 
 
 def read_metric(name: str, ctx: Context) -> Optional[float]:
@@ -345,35 +385,61 @@ def free(*trees) -> None:
     gc.collect()
 
 
-def per_layer(cell, cfg, kind: str, trace_dir: str, seg_t0: float,
-              served_perf, calls: Calls) -> dict:
-    """The per-layer metrics, device busy time and breakdown of a traced
-    segment that opened at ``seg_t0`` (``perf_counter`` s)."""
+def traced_context(cell, cfg, kind: str, trace_dir: str, seg_t0: float,
+                   served_perf, calls: Calls, texts: dict) -> Context:
+    """What the readers read of a traced segment that opened at ``seg_t0``
+    (``perf_counter`` s): its trace, the decode records with the program's
+    span arguments, and the scope map of the decode programs that ran,
+    bound from ``texts`` (``program_trace.decode_texts``)."""
     from . import peaks
     t_load = time.perf_counter()
     trace = tr.load(trace_dir)
     print(f"trace: {sum(map(len, trace.ops))} device ops read in "
           f"{time.perf_counter() - t_load:.1f}s", file=sys.stderr)
-    clip = [trace.window]
     lo, hi = trace.window
     to_ns = lambda t: lo + (t - seg_t0) * 1e9
     served = tr.Clip((max(to_ns(a), lo), min(to_ns(b), hi))
                      for a, b in served_perf
                      if to_ns(b) > lo and to_ns(a) < hi).iv
-    ctx = Context(cell, cfg, peaks.peaks(kind), calls, trace, clip, served,
-                  sum(b - a for a, b in served) * 1e-9)
+    calls = with_program(calls, trace)
+    smap = pt.scope_map(trace, calls.decode, texts,
+                        family.load(cell.spec).SCOPES)
+    return Context(cell, cfg, peaks.peaks(kind), calls, trace,
+                   [trace.window], served,
+                   sum(b - a for a, b in served) * 1e-9, scope_map=smap)
+
+
+def device_scopes(ctx: Context) -> Dict[str, float]:
+    """The decode programs' device seconds in the window by scope, with
+    ``program_trace.UNMAPPED`` always present."""
+    by_scope = pt.scope_ns(ctx.trace, pt.DECODE_PROGRAMS, ctx.window_ns,
+                           ctx.scope_map or {})
+    return {pt.UNMAPPED: 0.0, **{k: v * 1e-9 for k, v in by_scope.items()}}
+
+
+def per_layer(ctx: Context) -> dict:
+    """The per-layer metrics, device busy time and breakdown of a traced
+    segment."""
     metrics = {}
-    for m in cell.per_layer:
+    for m in ctx.cell.per_layer:
         v = read_metric(m["name"], ctx)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    clip, trace = ctx.window_ns, ctx.trace
+    lo, hi = trace.window
+    scopes = device_scopes(ctx)
+    top = sorted(scopes.items(), key=lambda kv: -kv[1])
+    top = [kv for kv in top if kv[0] != pt.UNMAPPED][:9]
     return {"metrics": metrics,
             "device": {"busy_s": tr.busy_ns(trace, clip) * 1e-9,
                        "window_s": (hi - lo) * 1e-9},
             "breakdown": {
                 "device_ops": [list(x) for x in tr.top_ops(trace, clip)],
-                "idle_gaps": [list(x) for x in tr.gaps_by_host(trace,
-                                                               served)]},
+                "idle_gaps": [list(x) for x in tr.gaps_by_host(
+                    trace, ctx.served_ns)],
+                "device_scopes": [list(x) for x in top]
+                + [[pt.UNMAPPED, scopes[pt.UNMAPPED]]]},
+            "scope_coverage": pt.coverage(scopes),
             "program_s": tr.program_seconds(trace, clip)}
 
 
@@ -451,6 +517,16 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     for span_ in loop.served:
         span_[1] = span_[1] or t_drained
     mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    if traced:
+        # the decode programs' texts, after the window and the drain: the
+        # twins compiled here build nothing the window or set-up ran
+        t_map = time.perf_counter()
+        seg_calls = calls.window(seg["t0"], t1)
+        texts = pt.decode_texts(adapter, engine,
+                                {c.k for c in seg_calls.decode})
+        map_s = time.perf_counter() - t_map
+        print(f"trace: {len(texts)} decode programs' texts in {map_s:.1f}s",
+              file=sys.stderr)
 
     recs = loop.records
     delivered = [r for r in recs if r.delivered is not None]
@@ -462,8 +538,10 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": mem}
     if traced:
-        layers = per_layer(cell, cfg, dev.device_kind, tmp, seg["t0"],
-                           loop.served, calls.window(seg["t0"], t1))
+        layers = per_layer(traced_context(cell, cfg, dev.device_kind, tmp,
+                                          seg["t0"], loop.served, seg_calls,
+                                          texts))
+        layers["scope_map_s"] = map_s
         shutil.rmtree(tmp, ignore_errors=True)
         result["metrics"] = layers.pop("metrics")
         device.update(layers.pop("device"))

@@ -3,8 +3,8 @@ window, in percent (a fused stretch of k steps counts k times)."""
 
 
 def read(ctx):
-    steps = sum(k for _, k, _, _ in ctx.calls.decode)
+    steps = sum(c.k for c in ctx.calls.decode)
     if not steps:
         return None
-    rows = sum(k * len(depths) for _, k, depths, _ in ctx.calls.decode)
+    rows = sum(c.k * len(c.depths) for c in ctx.calls.decode)
     return 100.0 * rows / (steps * ctx.n_slots)

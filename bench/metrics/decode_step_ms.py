@@ -10,7 +10,7 @@ PROGRAMS = ("jit_paged_decode1", "jit_run")
 
 
 def read(ctx):
-    steps = sum(k for _, k, _, _ in ctx.calls.decode)
+    steps = sum(c.k for c in ctx.calls.decode)
     ns = tr.module_ns(ctx.trace, PROGRAMS, ctx.window_ns)
     if not steps or not ns:
         return None

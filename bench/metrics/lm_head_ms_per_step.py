@@ -2,9 +2,9 @@
 ``lm_head`` (final norm, the head's product over the vocabulary, argmax),
 per decode step in the window, in ms.
 
-Scopes come from the compiled programs' HLO (``bench.program_trace``);
-a program without named scopes, or a map that covers under 95 % of the
-decode programs' device time, reads nothing."""
+Scopes come from the compiled executables that ran
+(``bench.program_trace``); a program without named scopes, or a map that
+covers under 95 % of the decode programs' device time, reads nothing."""
 
 from bench import program_trace as pt
 

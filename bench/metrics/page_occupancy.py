@@ -3,7 +3,7 @@ pages), sampled at every decode step in the window, in percent."""
 
 
 def read(ctx):
-    steps = sum(k for _, k, _, _ in ctx.calls.decode)
+    steps = sum(c.k for c in ctx.calls.decode)
     if not steps:
         return None
-    return 100.0 * sum(k * occ for _, k, _, occ in ctx.calls.decode) / steps
+    return 100.0 * sum(c.k * c.occupancy for c in ctx.calls.decode) / steps

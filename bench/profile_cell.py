@@ -12,8 +12,10 @@ JSON line: the median host microseconds per step in each stretch; of the
 profiled stretch, the decode programs' device seconds by named scope and
 the scope map's coverage, idle device time by program span (and by the
 harness's spans), and ``kv_cache_ms_per_step``, ``lm_head_ms_per_step``,
-``decode_step_ms`` and ``host_idle_ms_per_step``.  No correctness check:
-the benchmark's own run makes it.
+``decode_step_ms`` and ``host_idle_ms_per_step``.  The readings go
+through the harness's own ``traced_context``, with the scope map built
+from the same executables' texts as in a traced benchmark run.  No
+correctness check: the benchmark's own run makes it.
 """
 
 from __future__ import annotations
@@ -39,7 +41,7 @@ def main(argv=None) -> int:
 
     import jax
 
-    from bench import cells, harness, peaks
+    from bench import cells, harness
     from bench import program_trace as pt
     from bench import trace as tr
     cell = cells.load_cell(args.workload, ROOT)
@@ -82,40 +84,29 @@ def main(argv=None) -> int:
     loop, t0, t1, on_us, on_n = stretch(span("window"))
     jax.profiler.stop_trace()
 
-    trace = tr.load(args.out)
-    spans = pt.load_spans(args.out)
-    lo, hi = trace.window
-    to_ns = lambda t: lo + (t - t0) * 1e9
-    served = tr.Clip((max(to_ns(a), lo), min(to_ns(b), hi))
-                     for a, b in loop.served
-                     if to_ns(b) > lo and to_ns(a) < hi).iv
     window_calls = calls.window(t0, t1)
-    kind = jax.devices()[0].device_kind
-    ctx = harness.Context(cell, cfg, peaks.peaks(kind), window_calls, trace,
-                          [trace.window], served,
-                          sum(b - a for a, b in served) * 1e-9)
-    ks = {k for _, k, _, _ in window_calls.decode}
-    ctx.scope_map = pt.scope_map(pt.decode_hlo_texts(cell, cfg, ks))
-    by_scope = pt.scope_ns(trace, pt.DECODE_PROGRAMS, [trace.window],
-                           ctx.scope_map)
+    texts = pt.decode_texts(adapter, engine,
+                            {c.k for c in window_calls.decode})
+    ctx = harness.traced_context(cell, cfg, jax.devices()[0].device_kind,
+                                 args.out, t0, loop.served, window_calls,
+                                 texts)
+    trace, served = ctx.trace, ctx.served_ns
+    scopes = harness.device_scopes(ctx)
     result = {
         "workload": cell.name, "seed": args.seed,
         "host_us_per_step": {"unprofiled": off_us, "profiled": on_us},
         "steps": {"unprofiled": off_n, "profiled": on_n},
-        "serve_spans": len(spans),
-        "scope_coverage": pt.coverage(by_scope),
-        "device_scopes": sorted(
-            ([k, v * 1e-9] for k, v in by_scope.items()),
-            key=lambda kv: -kv[1]),
+        "serve_spans": len(ctx.spans),
+        "scope_coverage": pt.coverage(scopes),
+        "device_scopes": sorted(([k, v] for k, v in scopes.items()),
+                                key=lambda kv: -kv[1]),
         "idle_gaps_program": [list(x) for x in pt.gaps_by_program(
-            trace, spans, served)],
+            trace, ctx.spans, served)],
         "idle_gaps": [list(x) for x in tr.gaps_by_host(trace, served)],
         "metrics": {name: harness.read_metric(name, ctx) for name in (
             "kv_cache_ms_per_step", "lm_head_ms_per_step",
-            "decode_step_ms", "device_idle_share")},
+            "decode_step_ms", "device_idle_share", "host_idle_ms_per_step")},
     }
-    result["metrics"]["host_idle_ms_per_step"] = pt.host_idle_ms_per_step(
-        trace, spans, served)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -7,11 +7,16 @@ Serves what ``bench/record_trace.py`` serves (eight short requests through
 the paged engine at a small size, with the harness's host annotations)
 under the profiler; the engine's ``serve.*`` spans come with it.  Writes
 the ``.xplane.pb``, the profiler's Perfetto JSON export of the same trace,
-the compiled HLO text of the decode programs that ran
-(``hlo.json.gz``: program name -> texts) and ``expected.json``:
-``record_trace``'s numbers, and the numbers ``program_trace`` must give,
-worked out here from the Perfetto export and the HLO text by code of its
-own.
+the compiled HLO text of the decode executables that ran (``hlo.json.gz``:
+program name -> texts, by fused length) and of their twins compiled with
+full name paths (``hlo_named.json.gz``, the same layout; both from
+``program_trace.decode_texts``), and ``expected.json``: ``record_trace``'s
+numbers, and the numbers ``program_trace`` must give, worked out here from
+the Perfetto export and the HLO text by code of its own.
+
+A recording made while the program compiled with full name paths (before
+``repro.launch.compile_cache`` turned them off) has no
+``hlo_named.json.gz``: its texts carry the paths themselves.
 """
 
 from __future__ import annotations
@@ -27,54 +32,65 @@ import sys
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-SCOPES = ("embed", "attention", "kv_write", "mlp", "lm_head", "page_gather",
-          "page_scatter")
 DECODE = ("jit_paged_decode1", "jit_run")
 
 
-class SmallCell:
-    """``record_trace.serve_small``'s engine configuration, as a cell."""
-
-    @staticmethod
-    def engine_config():
-        from repro.serve.engine import EngineConfig
-        return EngineConfig(n_slots=4, max_prompt_len=64, max_new_cap=16,
-                            cache_len=80, page_size=16)
-
-
-def small_config():
-    from repro.models.config import ModelConfig
-    return ModelConfig(name="small", family="dense", n_layers=2, d_model=256,
-                       n_heads=4, n_kv_heads=2, d_ff=512, vocab_size=512,
-                       head_dim=64, tp=1)
+def load_texts(path: pathlib.Path):
+    """``hlo.json.gz`` and its twins (the same texts where the recording
+    has no ``hlo_named.json.gz``)."""
+    read = lambda name: json.loads(gzip.decompress(
+        (path / name).read_bytes()))
+    texts = read("hlo.json.gz")
+    named = read("hlo_named.json.gz") if (
+        path / "hlo_named.json.gz").is_file() else texts
+    return texts, named
 
 
-def op_scopes(texts) -> dict:
-    """(program, operation) -> scope or None, from HLO text lines
-    ``%name = ... op_name="a/b/c"``; an operation named differently by two
-    texts of one program maps to "?"."""
-    out = {}
-    for prog, variants in texts.items():
-        for text in variants:
-            for line in text.splitlines():
-                line = line.strip()
-                if line.startswith("ROOT "):
-                    line = line[5:]
-                if not line.startswith("%") or " = " not in line:
-                    continue
-                name = line[1:line.index(" = ")]
-                m = re.search(r'op_name="([^"]*)"', line)
-                parts = m.group(1).split("/") if m else []
-                scope = next((p for p in parts if p in SCOPES), None)
-                key = (prog, name)
-                out[key] = scope if out.get(key, scope) == scope else "?"
+def by_length(texts, ks) -> dict:
+    """k -> text: ``jit_paged_decode1`` holds k = 1, ``jit_run`` the other
+    lengths in ``ks``, ascending."""
+    longer = sorted(k for k in set(ks) if k > 1)
+    out = {k: t for k, t in zip(longer, texts.get("jit_run", []))}
+    if "jit_paged_decode1" in texts:
+        out[1] = texts["jit_paged_decode1"][0]
     return out
 
 
-def program_expected(perfetto, texts) -> dict:
+def op_scopes(ran: str, named: str) -> dict:
+    """Operation -> scope or None of the executable whose text is ``ran``,
+    each instruction's scope read from the instruction at the same place
+    in ``named``, from lines ``%name = ... op_name="a/b/c"``; {} where the
+    two texts differ in their count of instructions or in an opcode."""
+    from bench import family
+    scopes = set(family.load({"family": "dense"}).SCOPES)
+
+    def lines(text):
+        out = []
+        for line in text.splitlines():
+            line = line.strip()
+            if line.startswith("ROOT "):
+                line = line[5:]
+            if not line.startswith("%") or " = " not in line:
+                continue
+            opcode = re.search(r" ([a-z][\w\-]*)\(", line)
+            m = re.search(r'op_name="([^"]*)"', line)
+            parts = m.group(1).split("/") if m else []
+            out.append((line[1:line.index(" = ")],
+                        opcode.group(1) if opcode else None,
+                        next((p for p in parts if p in scopes), None)))
+        return out
+    a, b = lines(ran), lines(named)
+    if [x[1] for x in a] != [y[1] for y in b]:
+        return {}
+    return {x[0]: y[2] for x, y in zip(a, b)}
+
+
+def program_expected(perfetto, texts, named) -> dict:
     """Scope coverage, device seconds and operations by scope, the two
     scoped metrics per decode step, host idle ms per step, idle seconds by
-    program span, from the Perfetto JSON export and the HLO texts.  (The
+    program span, from the Perfetto JSON export and the HLO texts.  The
+    n-th decode run in the window is bound to the n-th ``serve.decode``
+    span's ``k``, and so to that length's texts.  (The
     export keeps picoseconds; the ``.xplane.pb`` reader whole nanoseconds,
     so each operation may read up to 1 ns shorter there.)"""
     events = json.loads(gzip.open(perfetto).read())
@@ -93,27 +109,39 @@ def program_expected(perfetto, texts) -> dict:
     lo, hi = span([e for e in xs if e["name"] == "bench.window"][0])
     on = lambda kind: [e for e in xs if e["pid"] == dev
                        and thread[(dev, e["tid"])] == kind]
-    mods = sorted((span(e), e["name"].split("(")[0])
+    mods = sorted((span(e), e["name"].split("(")[0], e["name"])
                   for e in on("XLA Modules"))
     ops = sorted((span(e), e["name"].split(" = ")[0].lstrip("%"))
                  for e in on("XLA Ops"))
-    scopes = op_scopes(texts)
+    serve = [(span(e), e["name"], e.get("args", {})) for e in xs
+             if e["name"].startswith("serve.")]
+    decode_ks = [int(args["k"]) for (a, _), n, args in
+                 sorted(serve, key=lambda x: x[0])
+                 if n == "serve.decode" and lo <= a < hi]
+    runs = [run for (a, _), prog, run in mods if prog in DECODE
+            and lo <= a < hi]
+    bound = collections.defaultdict(set)
+    for run, k in zip(runs, decode_ks if len(decode_ks) == len(runs)
+                      else []):
+        bound[run].add(k)
+    all_ks = {int(args["k"]) for _, n, args in serve if n == "serve.decode"}
+    ran, twin = by_length(texts, all_ks), by_length(named, all_ks)
+    scopes = {k: op_scopes(ran[k], twin[k]) for k in ran}
     by_scope, n_ops = collections.Counter(), collections.Counter()
     for i, ((a, b), name) in enumerate(ops):
         holds = i + 1 < len(ops) and ops[i + 1][0][0] < b  # a while or call
-        prog = [n for (s0, s1), n in mods if s0 <= a < s1]
-        if holds or not prog or prog[0] not in DECODE:
+        prog = [(n, run) for (s0, s1), n, run in mods if s0 <= a < s1]
+        if holds or not prog or prog[0][0] not in DECODE:
             continue
         t = max(0, min(b, hi) - max(a, lo))
-        key = (prog[0], name)
-        scope = ("unmapped" if key not in scopes or scopes[key] == "?"
-                 else scopes[key] or "unscoped")
+        ks = bound.get(prog[0][1], set())
+        of = scopes.get(next(iter(ks))) if len(ks) == 1 else None
+        scope = ("unmapped" if of is None or name not in of
+                 else of[name] or "unscoped")
         by_scope[scope] += t * 1e-9
         n_ops[scope] += t > 0
     total = sum(by_scope.values())
 
-    serve = [(span(e), e["name"], e.get("args", {})) for e in xs
-             if e["name"].startswith("serve.")]
     steps = sum(int(args["k"]) for (a, _), n, args in serve
                 if n == "serve.decode" and lo <= a < hi)
     n_step = sum(1 for (a, _), n, _ in serve
@@ -167,16 +195,22 @@ def main(argv=None) -> int:
     from bench import record_trace
     if not args.expected_only:
         from bench import program_trace as pt
-        record_trace.main(["--out", str(out)])
-        spans = pt.load_spans(str(out / "trace.xplane.pb"))
+        from bench import trace as tr
+        run = record_trace.record(out)
+        spans = tr.load(str(out / "trace.xplane.pb")).spans
         ks = {int(s["k"]) for _, _, n, s in spans if n == "serve.decode"}
-        texts = pt.decode_hlo_texts(SmallCell(), small_config(), ks)
-        (out / "hlo.json.gz").write_bytes(
-            gzip.compress(json.dumps(texts).encode()))
-    texts = json.loads(gzip.decompress((out / "hlo.json.gz").read_bytes()))
+        pairs = pt.decode_texts(run.adapter, run.engine, ks)
+        for i, name in enumerate(("hlo.json.gz", "hlo_named.json.gz")):
+            texts = collections.defaultdict(list)
+            for k in sorted(pairs):
+                texts[DECODE[k > 1]].append(pairs[k][i])
+            (out / name).write_bytes(
+                gzip.compress(json.dumps(texts).encode()))
+    texts, named = load_texts(out)
     expected = record_trace.expected_from_perfetto(
         out / "perfetto.trace.json.gz")
-    expected.update(program_expected(out / "perfetto.trace.json.gz", texts))
+    expected.update(program_expected(out / "perfetto.trace.json.gz", texts,
+                                     named))
     (out / "expected.json").write_text(json.dumps(expected, indent=1) + "\n")
     print(json.dumps(expected, indent=1))
     return 0

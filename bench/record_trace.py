@@ -29,7 +29,8 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 def serve_small():
     """A small engine, instrumented as the harness instruments a traced
-    run; returns a function that serves eight requests, then sleeps."""
+    run; returns a function that serves eight requests, then sleeps (with
+    the engine and its adapter as ``run.engine`` and ``run.adapter``)."""
     import numpy as np
 
     from bench import family, harness
@@ -65,6 +66,7 @@ def serve_small():
             time.sleep(sleep_s)
 
     run(0.0)                      # compiles every shape
+    run.engine, run.adapter = engine, adapter
     return run
 
 
@@ -137,6 +139,30 @@ def write_expected(out: pathlib.Path) -> None:
     print(json.dumps(expected, indent=1))
 
 
+def record(out: pathlib.Path):
+    """Serve under the profiler and write the ``.xplane.pb`` and the
+    Perfetto export under ``out``; returns ``serve_small``'s function."""
+    import jax
+    run = serve_small()
+    tmp = tempfile.mkdtemp()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(tmp, create_perfetto_trace=True,
+                             profiler_options=opts)
+    with jax.profiler.TraceAnnotation("bench.window"):
+        run(0.05)
+        run(0.05)
+    jax.profiler.stop_trace()
+    out.mkdir(parents=True, exist_ok=True)
+    for pattern, name in (("*.xplane.pb", "trace.xplane.pb"),
+                          ("*.trace.json.gz", "perfetto.trace.json.gz")):
+        found = glob.glob(f"{tmp}/**/{pattern}", recursive=True)[0]
+        shutil.copy(found, out / name)
+    shutil.rmtree(tmp)
+    return run
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=str(ROOT / "bench/testdata/trace"))
@@ -146,24 +172,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     out = pathlib.Path(args.out)
     if not args.expected_only:
-        import jax
-        run = serve_small()
-        tmp = tempfile.mkdtemp()
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.enable_hlo_proto = False
-        jax.profiler.start_trace(tmp, create_perfetto_trace=True,
-                                 profiler_options=opts)
-        with jax.profiler.TraceAnnotation("bench.window"):
-            run(0.05)
-            run(0.05)
-        jax.profiler.stop_trace()
-        out.mkdir(parents=True, exist_ok=True)
-        for pattern, name in (("*.xplane.pb", "trace.xplane.pb"),
-                              ("*.trace.json.gz", "perfetto.trace.json.gz")):
-            found = glob.glob(f"{tmp}/**/{pattern}", recursive=True)[0]
-            shutil.copy(found, out / name)
-        shutil.rmtree(tmp)
+        record(out)
     write_expected(out)
     return 0
 

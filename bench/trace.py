@@ -7,7 +7,9 @@ The trace is the ``.xplane.pb`` that ``jax.profiler`` writes.  What is read:
   operation);
 - the host plane's events whose names start with ``bench.``: the harness's
   own ``TraceAnnotation`` spans around each call into the engine's layers,
-  and ``bench.window`` around the measured window.
+  and ``bench.window`` around the measured window;
+- the host plane's events whose names start with ``serve.``: the program's
+  own spans (``repro.obs.ProfilerTracer``), with their arguments.
 
 Host and device events share one clock in nanoseconds.
 """
@@ -23,22 +25,28 @@ from typing import Dict, Iterable, List, Sequence, Tuple
 
 WINDOW = "bench.window"
 PREFIX = "bench."
+PROGRAM = "serve."
 UNTRACED = "host outside the harness's spans"
 
 Interval = Tuple[float, float]
+Span = Tuple[float, float, str, dict]      # start, end, name, arguments
 
 
 @dataclasses.dataclass
 class Trace:
     window: Interval                                   # ns
-    modules: List[List[Tuple[float, float, str]]]      # per device
+    # per device: (start, end, program, id of the executable that ran)
+    modules: List[List[Tuple[float, float, str, str]]]
     ops: List[List[Tuple[float, float, str]]]          # per device
     host: List[Tuple[float, float, str]]               # bench.* spans
+    spans: List[Span] = dataclasses.field(default_factory=list)  # serve.*
 
 
-def module_name(event_name: str) -> str:
-    """``jit_paged_decode1(2635...)`` -> ``jit_paged_decode1``."""
-    return event_name.split("(", 1)[0]
+def module_run(event_name: str) -> Tuple[str, str]:
+    """``jit_paged_decode1(2635...)`` -> ``("jit_paged_decode1",
+    "2635...")``: the program and the id of the executable that ran."""
+    name, _, rest = event_name.partition("(")
+    return name, rest.rstrip(")")
 
 
 def op_name(event_name: str) -> str:
@@ -55,14 +63,14 @@ def load(path: str) -> Trace:
         path = found[0]
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    modules, ops, host = [], [], []
+    modules, ops, host, spans = [], [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             mods, devops = [], []
             for line in plane.lines:
                 if line.name == "XLA Modules":
                     mods = [(e.start_ns, e.start_ns + e.duration_ns,
-                             module_name(e.name)) for e in line.events]
+                             *module_run(e.name)) for e in line.events]
                 elif line.name == "XLA Ops":
                     devops = [(e.start_ns, e.start_ns + e.duration_ns,
                                op_name(e.name)) for e in line.events]
@@ -74,11 +82,15 @@ def load(path: str) -> Trace:
                     if e.name.startswith(PREFIX):
                         host.append((e.start_ns, e.start_ns + e.duration_ns,
                                      e.name))
+                    elif e.name.startswith(PROGRAM):
+                        spans.append((e.start_ns, e.start_ns + e.duration_ns,
+                                      e.name, dict(e.stats)))
     windows = [h for h in host if h[2] == WINDOW]
     if len(windows) != 1:
         raise ValueError(f"{len(windows)} {WINDOW} spans in {path}")
     return Trace(window=windows[0][:2], modules=modules, ops=ops,
-                 host=[h for h in host if h[2] != WINDOW])
+                 host=[h for h in host if h[2] != WINDOW],
+                 spans=sorted(spans, key=lambda s: (s[0], -s[1])))
 
 
 def merge(intervals: Iterable[Interval]) -> List[Interval]:
@@ -135,7 +147,7 @@ def module_ns(trace: Trace, names: Iterable[str],
     devices."""
     names, c = set(names), Clip(clip)
     return sum(c.length(a, b)
-               for dev in trace.modules for a, b, n in dev if n in names)
+               for dev in trace.modules for a, b, n, _ in dev if n in names)
 
 
 def program_seconds(trace: Trace, clip: Sequence[Interval]) -> Dict[str, float]:
@@ -144,7 +156,7 @@ def program_seconds(trace: Trace, clip: Sequence[Interval]) -> Dict[str, float]:
     c = Clip(clip)
     total: Dict[str, float] = collections.Counter()
     for dev in trace.modules:
-        for a, b, n in dev:
+        for a, b, n, _ in dev:
             total[n] += c.length(a, b) * 1e-9
     return dict(sorted(total.items(), key=lambda kv: -kv[1]))
 

@@ -99,7 +99,7 @@ def test_dense_costs_are_the_parents(name):
                       .read_text())
     fam = family.load(spec)
     cfg = fam.model_config(spec)
-    call = lambda k: (0.0, k, [100, 900, 1788], 0.5)
+    call = lambda k: harness.Decode(0.0, k, [100, 900, 1788], 0.5)
     got = {"weight_bytes": fam.weight_bytes(cfg),
            "decode_step_bytes_k1": fam.decode_step_bytes(cfg, call(1)),
            "decode_step_bytes_k4": fam.decode_step_bytes(cfg, call(4)),

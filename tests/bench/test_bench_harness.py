@@ -54,6 +54,32 @@ def test_sound_run_is_correct(loop, qk_norm):
     json.dumps(r)
 
 
+def test_traced_run_keeps_its_line_and_maps_scopes_after_the_window(
+        monkeypatch):
+    """A traced run on the CPU, whose trace holds no device planes: the
+    decode programs' texts are taken after the window, the breakdown names
+    the decode programs' time by scope (none here) and the line keeps its
+    shape, ``checks`` last.  (The peaks table has no CPU; the readers here
+    read none.)"""
+    import time
+
+    from bench import peaks
+    v5e = peaks.peaks("TPU v5 lite")
+    monkeypatch.setattr(peaks, "peaks", lambda kind: v5e)
+    per = [{"name": n, "unit": "ms"} for n in (
+        "host_idle_ms_per_step", "lm_head_ms_per_step")]
+    cell = cells.Cell("small.open", 1, small_spec(), small_traffic("open"),
+                      E2E, per)
+    r = harness.run_cell(cell, 4294967311, 1.5, True, time.perf_counter(),
+                         limits=LIMITS)
+    assert r["correct"] is True, r["checks"]
+    assert list(r)[-1] == "checks"
+    assert r["scope_map_s"] > 0
+    assert r["breakdown"]["device_scopes"] == [["unmapped", 0.0]]
+    assert set(r["metrics"]) <= {"host_idle_ms_per_step"}
+    json.dumps(r)
+
+
 def _break(monkeypatch, fault):
     from repro.serve.engine import PagedTransformerModel as P
     decode, prefill = P.decode_multi, P.prefill

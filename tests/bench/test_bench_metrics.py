@@ -23,7 +23,8 @@ def ctx():
     cell = cells.Cell("small.open", 1, small_spec(), small_traffic(), [], [])
     cfg = dense.model_config(cell.spec)
     calls = harness.Calls(
-        decode=[(0.0, 1, [20, 40, 60], 0.25), (0.1, 4, [21, 41], 0.5)],
+        decode=[harness.Decode(0.0, 1, [20, 40, 60], 0.25),
+                harness.Decode(0.1, 4, [21, 41], 0.5)],
         prefill=[(0.0, [16, 48]), (0.2, [16])])
     win = [t.window]
     served_s = (t.window[1] - t.window[0]) * 1e-9
@@ -56,9 +57,9 @@ def test_roofline_and_mfu_from_costs(ctx):
     cfg, pk = ctx.cfg, ctx.peaks
     p = _expected()["programs"]
     dec_s = p["jit_paged_decode1"]["seconds"] + p["jit_run"]["seconds"]
-    # one step of a call: (time, k=1, depths, occupancy); the k=4 call's
+    # one step of a call: a record with k=1; the k=4 call's
     # steps attend one position further each
-    step = lambda depths: (0.0, 1, depths, 0.0)
+    step = lambda depths: harness.Decode(0.0, 1, depths, 0.0)
     need = dense.decode_step_bytes(cfg, step([20, 40, 60])) + sum(
         dense.decode_step_bytes(cfg, step([21 + i, 41 + i]))
         for i in range(4))
