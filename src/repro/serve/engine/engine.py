@@ -202,6 +202,19 @@ class PagedTransformerModel(TransformerModel):
     table *inside* the jit (serve.step paged builders), so the paged plane
     adds zero dispatches over the slot plane.
 
+    Every program that writes the pool donates it, so it updates the pool
+    in place.  A call (``prefill`` or ``decode``) consumes the pool it is
+    given only if that pool is the one the adapter last returned, and if
+    a pool this call returned has come back before: its caller threads
+    the pool through instead of keeping it.  That is the engine's own
+    chain, where the cache is always the previous call's output.  Any
+    other pool is first copied on the device, and the copy is donated, so
+    the caller's pool stays intact: the initial pool, one a caller keeps
+    and passes again (the benchmark's warm-up), and the input of a call
+    whose outputs never come back (a caller that throws them away).
+    ``kv_pool_in_place`` and ``kv_pool_copied`` count the two cases; an
+    engine copies twice, at its first prefill and its first decode.
+
     Restricted to purely-causal attention caches (dense/moe, no window):
     recurrent state mixes batch axes and ring-windowed caches wrap
     positions mod the window, neither of which pages cleanly.
@@ -214,9 +227,16 @@ class PagedTransformerModel(TransformerModel):
                 "paged KV serving supports purely-causal attention caches "
                 "(dense/moe families, window == 0); recurrent and "
                 "windowed caches do not page cleanly")
-        from ..step import make_paged_decode_scan, make_paged_decode_step
+        from ..step import make_paged_decode_step
         from .cache_pool import scatter_page_view
         self._paged: Optional[PagedCachePool] = None
+        # the engine hands over its registry, as it does its tracer
+        self.metrics = MetricsRegistry()
+        # the pool the last pool-writing call returned (the object itself:
+        # an id can be recycled) and that call; the calls whose pools have
+        # come back, whose callers therefore thread the pool through
+        self._last_pool, self._last_call = None, None
+        self._threaded = set()
 
         def paged_group_prefill(view_len, params, tokens, lengths, slots,
                                 tables, pool, tok_vec, pos_vec):
@@ -249,17 +269,49 @@ class PagedTransformerModel(TransformerModel):
                                  write_table)
             return pool, nxt, nxt, pos + 1
 
-        self._paged_prefill = jax.jit(paged_group_prefill, static_argnums=0)
-        self._paged_decode1 = jax.jit(paged_decode1)
+        self._paged_prefill = jax.jit(paged_group_prefill, static_argnums=0,
+                                      donate_argnames="pool")
+        self._paged_decode1 = jax.jit(paged_decode1, donate_argnames="pool")
         self._paged_decode_k = {}
-        self._paged_scan_builder = (
-            lambda k: make_paged_decode_scan(self.cfg, rules, k))
+        self._copy_pool = jax.jit(
+            lambda pool: jax.tree_util.tree_map(jnp.copy, pool))
 
     def init_paged_pool(self, pool: PagedCachePool):
         """Bind the page allocator and build the device-side page pool:
         one batch row per physical page (+ the trash page)."""
         self._paged = pool
+        self._last_pool, self._last_call = None, None
+        self._threaded = set()
         return T.init_cache(self.cfg, pool.n_pages + 1, pool.page_size)
+
+    def paged_decode_scan(self, k: int):
+        """The jitted k-step decode scan, built once per k; it donates the
+        pool like the one-step program."""
+        if k not in self._paged_decode_k:
+            from ..step import make_paged_decode_scan
+            self._paged_decode_k[k] = jax.jit(
+                make_paged_decode_scan(self.cfg, self.rules, k),
+                donate_argnames="pool")
+        return self._paged_decode_k[k]
+
+    def _consumable(self, pool, call: str):
+        """What ``call``'s donating program may consume: ``pool`` itself if
+        it is the adapter's last output and ``call`` is threaded, else a
+        device copy of it."""
+        if pool is self._last_pool:
+            self._threaded.add(self._last_call)
+            if call in self._threaded:
+                self.metrics.counter("kv_pool_in_place").inc()
+                return pool
+        # let the last output go before the copy is made, so that no more
+        # pools are alive at once than without donation
+        self._last_pool = None
+        self.metrics.counter("kv_pool_copied").inc()
+        return self._copy_pool(pool)
+
+    def _returned(self, out, call: str):
+        self._last_pool, self._last_call = out[0], call
+        return out
 
     def _tables(self):
         # snapshots, never aliases: on CPU jnp.asarray can be ZERO-COPY
@@ -284,25 +336,26 @@ class PagedTransformerModel(TransformerModel):
         # fancy index copies — no alias of the live host map)
         with self.tracer.span("page_tables", lane="engine"):
             tables = jnp.asarray(self._paged.write_table[slots_np])
-        return self._paged_prefill(self._paged.view_len, self.params,
-                                   jnp.asarray(batch), jnp.asarray(lengths),
-                                   jnp.asarray(slots_np), tables, pool,
-                                   tok, pos)
+        return self._returned(self._paged_prefill(
+            self._paged.view_len, self.params, jnp.asarray(batch),
+            jnp.asarray(lengths), jnp.asarray(slots_np), tables,
+            self._consumable(pool, "prefill"), tok, pos), "prefill")
 
     def decode(self, pool, tok, pos):
         table, write_table = self._tables()
-        return self._paged_decode1(self.params, tok, pos, pool,
-                                   table, write_table)
+        return self._returned(self._paged_decode1(
+            self.params, tok, pos, self._consumable(pool, "decode"), table,
+            write_table), "decode")
 
     def decode_multi(self, pool, tok, pos, k: int):
         if k == 1:
             pool, nxt, tok, pos = self.decode(pool, tok, pos)
             return pool, nxt[None], tok, pos
-        if k not in self._paged_decode_k:
-            self._paged_decode_k[k] = jax.jit(self._paged_scan_builder(k))
+        scan = self.paged_decode_scan(k)
         table, write_table = self._tables()
-        return self._paged_decode_k[k](self.params, tok, pos, pool,
-                                       table, write_table)
+        return self._returned(scan(self.params, tok, pos,
+                                   self._consumable(pool, "decode"), table,
+                                   write_table), "decode")
 
 
 class ManualClock:
@@ -422,6 +475,8 @@ class ServingEngine:
         if hasattr(model, "tracer"):
             model.tracer = self.tracer
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        if hasattr(model, "metrics"):
+            model.metrics = self.metrics
         self.queue = RequestQueue(AdmissionLimits(
             max_prompt_len=config.max_prompt_len,
             max_new_cap=config.max_new_cap,

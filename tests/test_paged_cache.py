@@ -361,6 +361,162 @@ def test_paged_engine_single_request_exact(small_lm):
     np.testing.assert_array_equal(rep.completed[0], ref)
 
 
+def _pool_writes(model):
+    """Record each of the adapter's pool-writing calls and the pool it was
+    given (``decode`` is reached through ``decode_multi``)."""
+    given = []
+    for name in ("prefill", "decode_multi"):
+        def call(pool, *a, _fn=getattr(model, name), _name=name):
+            given.append((_name, pool))
+            return _fn(pool, *a)
+        setattr(model, name, call)
+    return given
+
+
+def _small_paged_engine(small_lm, n=6):
+    cfg, params = small_lm
+    workload = synthetic_workload(n, cfg.vocab_size, lens=(5, 9, 13),
+                                  news=(4, 9), stagger=0.5, seed=2)
+    ec = EngineConfig(n_slots=3, max_prompt_len=13, max_new_cap=9,
+                      cache_len=22, max_prefill_per_step=2, page_size=4)
+    model = PagedTransformerModel(params, cfg, RULES)
+    return model, ServingEngine(model, ec), workload
+
+
+def _assert_greedy(small_lm, completed, workload):
+    cfg, params = small_lm
+    for rid, (prompt, max_new, _) in enumerate(workload):
+        ref = np.asarray(greedy_generate(
+            params, cfg, RULES, np.asarray(prompt)[None],
+            max_new=max_new))[0]
+        np.testing.assert_array_equal(completed[rid], ref,
+                                      err_msg=f"rid {rid}")
+
+
+def _deleted(pool):
+    return [leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pool)]
+
+
+def _first_of_each(given):
+    names = [name for name, _ in given]
+    return {names.index("prefill"), names.index("decode_multi")}
+
+
+def test_paged_engine_updates_pool_in_place(small_lm):
+    """On the engine's own chain the first prefill and the first decode
+    copy the pool they are given (the initial pool; prefill's first
+    output, before any decode output has come back), and every other
+    dispatch consumes its pool, the previous dispatch's output, in place.
+    Tokens stay greedy's."""
+    model, eng, workload = _small_paged_engine(small_lm)
+    given = _pool_writes(model)
+    for p, m, a in workload:
+        eng.submit(p, m, arrival=a)
+    rep = eng.run()
+    n = len(given)
+    assert n > 3
+    copied = _first_of_each(given)
+    assert copied == {0, 1}
+    for i, (_, pool) in enumerate(given):
+        assert _deleted(pool) == [i not in copied] * 2, i
+    assert not any(_deleted(eng.cache))
+    counters = eng.metrics.snapshot()["counters"]
+    assert counters["kv_pool_copied"] == 2
+    assert counters["kv_pool_in_place"] == n - 2
+    _assert_greedy(small_lm, rep.completed, workload)
+
+
+@pytest.mark.parametrize("dropped", ["prefill", "decode_multi"])
+def test_paged_adapter_keeps_the_pool_of_a_caller_that_drops_outputs(
+        small_lm, dropped):
+    """A caller that throws one call's pools away and hands back the pool
+    it was given (a wrapper around the engine's calls) never has that pool
+    consumed: the run completes, every pool handed back is intact, and the
+    other call still runs in place once its own pools come back."""
+    model, eng, workload = _small_paged_engine(small_lm)
+    fn = getattr(model, dropped)
+    handed_back = []
+
+    def keep(pool, *a):
+        out = fn(pool, *a)
+        handed_back.append(pool)
+        return (pool,) + tuple(out[1:])
+    setattr(model, dropped, keep)
+    for p, m, a in workload:
+        eng.submit(p, m, arrival=a)
+    rep = eng.run()
+    assert len(rep.completed) == len(workload)
+    assert handed_back
+    for pool in handed_back:
+        assert not any(_deleted(pool))
+    in_place = eng.metrics.counter_value("kv_pool_in_place")
+    assert (in_place > 0) == (dropped == "prefill")
+
+
+def _warm_up_calls(model, pool, n_slots):
+    """The adapter's calls as the benchmark's warm-up makes them: on a
+    pool the caller keeps, outputs thrown away."""
+    tok, pos = model.token_state(n_slots)
+    prompts = [np.arange(1, 10, dtype=np.int32)] * 2
+    return {"prefill": lambda: model.prefill(pool, prompts, [0, 1], tok, pos),
+            "decode1": lambda: model.decode_multi(pool, tok, pos, 1),
+            "scan4": lambda: model.decode_multi(pool, tok, pos, 4)}
+
+
+@pytest.mark.parametrize("call", ["prefill", "decode1", "scan4"])
+def test_paged_adapter_leaves_a_kept_pool_intact(small_lm, call):
+    """A call repeated on a pool the caller keeps copies it each time:
+    the pool stays intact and unchanged, and both calls give the same
+    outputs."""
+    model, eng, _ = _small_paged_engine(small_lm)
+    kept = eng.cache
+    before = jax.tree_util.tree_map(np.asarray, kept)
+    run = _warm_up_calls(model, kept, eng.config.n_slots)[call]
+    first, again = run(), run()
+    for a, b in zip(jax.tree_util.tree_leaves(first),
+                    jax.tree_util.tree_leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not any(_deleted(kept))
+    for name, leaf in before.items():
+        np.testing.assert_array_equal(np.asarray(kept[name]), leaf)
+    assert eng.metrics.counter_value("kv_pool_copied") == 2
+    assert eng.metrics.counter_value("kv_pool_in_place") == 0
+
+
+def test_paged_engine_serves_after_warm_up(small_lm):
+    """Warm-up calls on the engine's pool, then the engine serves from
+    that pool: its first prefill and first decode copy, every other
+    dispatch runs in place, and the tokens are greedy's."""
+    model, eng, workload = _small_paged_engine(small_lm)
+    calls = _warm_up_calls(model, eng.cache, eng.config.n_slots)
+    for run in calls.values():
+        run()
+    given = _pool_writes(model)
+    for p, m, a in workload:
+        eng.submit(p, m, arrival=a)
+    rep = eng.run()
+    assert eng.metrics.counter_value("kv_pool_copied") == len(calls) + 2
+    assert eng.metrics.counter_value("kv_pool_in_place") == len(given) - 2
+    _assert_greedy(small_lm, rep.completed, workload)
+
+
+def test_paged_adapter_serves_engines_in_turn(small_lm):
+    """One adapter around two engines in turn (binding a page pool starts
+    a new chain): the second engine copies as a new one does, and never
+    consumes the first engine's last pool."""
+    model, first, workload = _small_paged_engine(small_lm)
+    for p, m, a in workload:
+        first.submit(p, m, arrival=a)
+    first.run()
+    second = ServingEngine(model, first.config)
+    for p, m, a in workload:
+        second.submit(p, m, arrival=a)
+    rep = second.run()
+    assert not any(_deleted(first.cache))
+    assert second.metrics.counter_value("kv_pool_copied") == 2
+    _assert_greedy(small_lm, rep.completed, workload)
+
+
 def test_paged_rejects_recurrent_families():
     cfg = get_reduced("recurrentgemma_9b")
     params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -800,13 +956,10 @@ def test_paged_decode_programs_hold_no_view(small_lm, k):
     one-step program and the fused scan) hold no array of the per-slot
     view's shape (L, n_slots, view_len, KV, hd) and no operation in a
     page_gather or page_scatter scope."""
-    from repro.serve.step import make_paged_decode_scan
     cfg, params = small_lm
     tok, pos, pool, table, write_table = _paged_state(cfg, k)
-    if k == 1:
-        fn = PagedTransformerModel(params, cfg, RULES)._paged_decode1
-    else:
-        fn = jax.jit(make_paged_decode_scan(cfg, RULES, k))
+    model = PagedTransformerModel(params, cfg, RULES)
+    fn = model._paged_decode1 if k == 1 else model.paged_decode_scan(k)
     text = fn.lower(params, tok, pos, pool, table,
                     write_table).compile().as_text()
     L, _, _, KV, hd = pool["k"].shape

@@ -13,6 +13,8 @@ tests may load the TPU library, and every test-runner worker must collect
 the same tests.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -113,6 +115,19 @@ def _reads_pages_in_place(compiled, llama):
     assert f"[{L},{N_SLOTS},{CACHE_LEN},{KV},{hd}]" not in hlo
 
 
+def _updates_pool_in_place(compiled, llama):
+    """The program aliases its output to the whole donated pool, and
+    copies no array of a pool leaf's shape."""
+    leaves = jax.tree_util.tree_leaves(llama["pool"])
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    hlo = compiled.as_text()
+    for shape in {a.shape for a in leaves}:
+        dims = ",".join(map(str, shape))
+        copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(", hlo)
+        assert not copies, copies
+
+
 def test_paged_decode_step(llama):
     m = llama["model"]
     compiled = m._paged_decode1.lower(
@@ -120,24 +135,28 @@ def test_paged_decode_step(llama):
         llama["table"], llama["table"]).compile()
     _fits(compiled)
     _reads_pages_in_place(compiled, llama)
+    _updates_pool_in_place(compiled, llama)
 
 
 def test_paged_decode_multi_scan(llama):
-    fn = jax.jit(llama["model"]._paged_scan_builder(4))
+    fn = llama["model"].paged_decode_scan(4)
     compiled = fn.lower(llama["params"], llama["vec"], llama["vec"],
                         llama["pool"], llama["table"],
                         llama["table"]).compile()
     _fits(compiled)
     _reads_pages_in_place(compiled, llama)
+    _updates_pool_in_place(compiled, llama)
 
 
 def test_paged_group_prefill(llama, one_chip):
     B, S = 2, 512
-    _fits(llama["model"]._paged_prefill.lower(
+    compiled = llama["model"]._paged_prefill.lower(
         CACHE_LEN, llama["params"], _i32((B, S), one_chip),
         _i32((B,), one_chip), _i32((B,), one_chip),
         _i32((B, PAGES_PER_SLOT), one_chip), llama["pool"], llama["vec"],
-        llama["vec"]).compile())
+        llama["vec"]).compile()
+    _fits(compiled)
+    _updates_pool_in_place(compiled, llama)
 
 
 @pytest.mark.parametrize("mode", ["layers", "allreduce", "scatter"])
